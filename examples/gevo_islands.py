@@ -44,6 +44,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import IslandOrchestrator, default_island_specs
 from repro.core.islands import TOPOLOGIES, plan
+from repro.launch.compile_cache import enable_compile_cache
 
 WORKLOADS = ("twofc", "mobilenet", "rmsnorm", "flash_attention",
              "mamba_scan", "joint")
@@ -105,6 +106,7 @@ def main():
                          "lets through (default 0.5)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.resume and not args.root:
         ap.error("--resume requires --root")
     if args.surrogate and args.engine == "tensor":

@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import minimize_patch
 from repro.core.evaluator import make_evaluator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels.workloads import (KERNELS, SHAPES, build_kernel_workload,
                                      evolve_kernel_schedule)
 
@@ -60,6 +61,7 @@ def main():
                     help="fraction of generated offspring the surrogate "
                          "lets through (default 0.5)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"Building {args.kernel} schedule workload "
           f"({SHAPES[args.kernel]}, {args.time_mode} time)...")

@@ -20,6 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import GevoML, OperatorWeights
 from repro.core.evaluator import make_evaluator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.workloads.mobilenet import build_mobilenet_prediction_workload
 
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--cache", default=None,
                     help="persistent fitness cache path (JSONL)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     t0 = time.time()
     print("Pretraining MobileNet on synthetic CIFAR10...")

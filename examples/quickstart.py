@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import GevoML, OperatorWeights, minimize_patch
 from repro.core.evaluator import make_evaluator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.workloads.twofc import build_twofc_training_workload
 
 
@@ -53,6 +54,7 @@ def main():
                     help="resume from the latest checkpoint in --checkpoint")
     ap.add_argument("--generations", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.resume and not args.checkpoint:
         ap.error("--resume requires --checkpoint")
     weights = OperatorWeights.parse(args.operators)

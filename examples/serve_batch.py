@@ -35,6 +35,9 @@ def main():
                          "ArtifactRegistry instead of the default")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs import smoke_config
     from repro.core.deploy import (ArtifactRegistry, ServeEngine,
                                    engine_schedule_from, oneshot_generate)

@@ -21,6 +21,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.tokens import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.optim.optimizers import adamw
 from repro.optim.schedules import wsd_schedule
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt", default="/tmp/repro_100m_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # qwen3 family scaled to ~100M params
     cfg = get_config("qwen3-0.6b").scaled(

@@ -331,13 +331,13 @@ class ServeEngine:
 
     # -- prefill (admission) -------------------------------------------------
     def _token_batch(self, cfg, tokens_2d, positions_2d):
-        import jax.numpy as jnp
-        b = {"tokens": jnp.asarray(tokens_2d),
-             "positions": jnp.asarray(positions_2d)}
+        # host arrays: jit transfers them straight to the device(s) holding
+        # this engine's parameters, never by way of the default device
+        b = {"tokens": np.asarray(tokens_2d, np.int32),
+             "positions": np.asarray(positions_2d, np.int32)}
         if cfg.mrope:
-            b["positions3"] = jnp.broadcast_to(
-                jnp.asarray(positions_2d)[:, :, None],
-                positions_2d.shape + (3,))
+            b["positions3"] = np.broadcast_to(
+                b["positions"][:, :, None], positions_2d.shape + (3,))
         return b
 
     def _n_in_flight(self) -> int:
@@ -377,6 +377,7 @@ class ServeEngine:
 
     def _admit(self) -> None:
         import jax
+        import jax.numpy as jnp
 
         from ...models.transformer import init_cache
         n_free = self.max_slots - self._n_in_flight()
@@ -406,8 +407,10 @@ class ServeEngine:
                 batch.caches = _stack_lanes(
                     [init_cache(cfg, 1, self.max_len)] * batch.n_lanes)
             free = batch.free_lanes()
+            # shapes only: the padded cache is built where the prefill cache
+            # lives (the replica's own device), not on the default device
+            full = jax.eval_shape(lambda: init_cache(cfg, 1, self.max_len))
             for i, req in enumerate(reqs):
-                full = init_cache(cfg, 1, self.max_len)
                 mine = _batch_axis_slice(pre_caches, i)
 
                 def splice(f, p, _plen=plen):
@@ -416,8 +419,11 @@ class ServeEngine:
                     if (f.ndim >= 3 and p.ndim == f.ndim
                             and p.shape[2] == _plen
                             and f.shape[2] == self.max_len):
-                        return f.at[:, :, :_plen].set(p)
-                    return f
+                        pad = [(0, 0)] * f.ndim
+                        pad[2] = (0, self.max_len - _plen)
+                        return jnp.pad(p.astype(f.dtype), pad)
+                    raise ValueError(f"prefill cache leaf {p.shape} does "
+                                     f"not fit decode cache {f.shape}")
                 one = jax.tree.map(splice, full, mine)
                 tok = int(first[i])
                 res = ServeResult(
@@ -447,7 +453,6 @@ class ServeEngine:
         logits)`` work items *without* blocking on the results — a router
         interleaves dispatches across replicas so each replica's compute
         overlaps its siblings' host work."""
-        import jax.numpy as jnp
         pending = []
         for variant in sorted(self.batches):
             batch = self.batches[variant]
@@ -467,13 +472,11 @@ class ServeEngine:
                 toks[i, 0, 0] = lane.last
                 pos[i, 0, 0] = lane.index
                 idx[i] = lane.index
-            tb = {"tokens": jnp.asarray(toks),
-                  "positions": jnp.asarray(pos)}
+            tb = {"tokens": toks, "positions": pos}
             if cfg.mrope:
-                tb["positions3"] = jnp.broadcast_to(
-                    jnp.asarray(pos)[..., None], (N, 1, 1, 3))
-            logits, batch.caches = dec_fn(self.params, tb, batch.caches,
-                                          jnp.asarray(idx))
+                tb["positions3"] = np.broadcast_to(pos[..., None],
+                                                   (N, 1, 1, 3))
+            logits, batch.caches = dec_fn(self.params, tb, batch.caches, idx)
             self.n_decode_batches += 1
             pending.append((variant, active, logits))
         return pending
@@ -650,9 +653,8 @@ def oneshot_generate(cfg, params, prompts: np.ndarray, gen: int,
     equal-length prompts) for ``--oneshot`` demos and convenience tests.
     Returns the ``(B, gen)`` continuation of ``prompts`` (greedy unless
     ``temperature`` > 0).  Note this runs through :class:`ServeEngine`
-    itself — the engine-independent correctness oracle is the direct
-    ``models.transformer`` prefill/decode loop (see
-    ``tests/test_serve.py``)."""
+    itself — the engine-independent correctness oracle is
+    :func:`repro.models.transformer.greedy_reference`."""
     engine = ServeEngine(cfg, params,
                          max_len=max_len or (prompts.shape[1] + gen),
                          max_slots=len(prompts),

@@ -420,8 +420,10 @@ def main(argv=None) -> int:
                         help="shrink the config to smoke size")
     parser.add_argument("--replicas", type=int, default=2)
     parser.add_argument("--mesh", default="",
-                        help="DATAxMODEL smoke mesh, e.g. 2x2 (requires "
-                             "that many XLA host devices); empty = no mesh")
+                        help="DATAxMODEL mesh over the attached devices, "
+                             "e.g. 4x1 gives each of 4 replicas its own "
+                             "chip (on the CPU: that many XLA host "
+                             "devices); empty = no mesh")
     parser.add_argument("--requests", type=int, default=8)
     parser.add_argument("--scenario", default="bursty")
     parser.add_argument("--max-prompt", type=int, default=12)

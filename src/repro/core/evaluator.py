@@ -342,6 +342,20 @@ def workload_fingerprint(workload) -> str:
 _WORKER_WORKLOAD = None
 
 
+def refuse_workers_on_tpu(what: str) -> None:
+    """Raise when the JAX backend is a TPU: ``what`` would spawn worker
+    processes that each open JAX, but a chip belongs to one process at a
+    time and the parent already holds it (it touched JAX building the
+    workload), so the workers would fail or hang instead of evaluating."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} spawns worker processes that each need the TPU, but "
+            "the chip belongs to the process that already holds it; on a "
+            "TPU, evaluation runs in the process that holds the chip "
+            "(serial evaluation, in-process islands)")
+
+
 def _worker_init(payload: dict) -> None:
     """Pool initializer: materialize the workload once per worker.  Runs in a
     freshly spawned interpreter, so this worker owns its JAX context."""
@@ -590,6 +604,8 @@ class ParallelEvaluator(Evaluator):
 
     def _ensure_pool(self):
         if self._pool is None:
+            refuse_workers_on_tpu(f"ParallelEvaluator(n_workers="
+                                  f"{self.n_workers})")
             ctx = mp.get_context(self.start_method)
             self._pool = ctx.Pool(self.n_workers, initializer=_worker_init,
                                   initargs=(self._payload(),))

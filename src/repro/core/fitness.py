@@ -79,28 +79,35 @@ class PredictionWorkload:
     # optional — this workload also pickles whole
     spec: object | None = None
 
+    def run(self, program: Program) -> tuple[float, float]:
+        """Execute and score ``program``; any failure propagates as raised
+        (the unpatched program and shipped baselines are run this way)."""
+        fn = jit_program(program)
+        n = (len(self.images) // self.batch) * self.batch
+        correct = 0
+        t_meas = 0.0
+        for i in range(0, n, self.batch):
+            inp = {"images": self.images[i:i + self.batch]}
+            if self.time_mode == "measured" and i == 0:
+                t_meas = measured_time(fn, inp) * (n // self.batch)
+            out = fn(inp)[0]
+            if out.ndim != 2 or out.shape[0] != self.batch:
+                raise InvalidVariant(f"bad logits shape {out.shape}")
+            pred = np.argmax(np.nan_to_num(np.asarray(out, np.float32),
+                                           nan=-1e30), axis=-1)
+            k = min(out.shape[1], int(self.labels.max()) + 1)
+            correct += int(np.sum(pred[: self.batch] ==
+                                  self.labels[i:i + self.batch]))
+        error = 1.0 - correct / max(n, 1)
+        t = t_meas if self.time_mode == "measured" else \
+            static_time(program) * (n // self.batch)
+        return _check_finite_scalar(t), _check_finite_scalar(error)
+
     def evaluate(self, program: Program) -> tuple[float, float]:
+        """:meth:`run` as the search sees it: a variant that fails to
+        execute is invalid (Section 4.3), whatever the exception."""
         try:
-            fn = jit_program(program)
-            n = (len(self.images) // self.batch) * self.batch
-            correct = 0
-            t_meas = 0.0
-            for i in range(0, n, self.batch):
-                inp = {"images": self.images[i:i + self.batch]}
-                if self.time_mode == "measured" and i == 0:
-                    t_meas = measured_time(fn, inp) * (n // self.batch)
-                out = fn(inp)[0]
-                if out.ndim != 2 or out.shape[0] != self.batch:
-                    raise InvalidVariant(f"bad logits shape {out.shape}")
-                pred = np.argmax(np.nan_to_num(np.asarray(out, np.float32),
-                                               nan=-1e30), axis=-1)
-                k = min(out.shape[1], int(self.labels.max()) + 1)
-                correct += int(np.sum(pred[: self.batch] ==
-                                      self.labels[i:i + self.batch]))
-            error = 1.0 - correct / max(n, 1)
-            t = t_meas if self.time_mode == "measured" else \
-                static_time(program) * (n // self.batch)
-            return _check_finite_scalar(t), _check_finite_scalar(error)
+            return self.run(program)
         except InvalidVariant:
             raise
         except Exception as e:  # any execution failure invalidates the variant
@@ -148,11 +155,15 @@ class KernelWorkload:
     # measures).
     feature_probe: Callable[[dict], dict] | None = None
 
+    def run(self, program: Program) -> tuple[float, float]:
+        """Decode, execute and score the genome; failures propagate."""
+        t, err = self.runner(self.space.decode(program))
+        return _check_finite_scalar(t), _check_finite_scalar(err)
+
     def evaluate(self, program: Program) -> tuple[float, float]:
+        """:meth:`run` as the search sees it: any failure is invalid."""
         try:
-            genome = self.space.decode(program)
-            t, err = self.runner(genome)
-            return _check_finite_scalar(t), _check_finite_scalar(err)
+            return self.run(program)
         except InvalidVariant:
             raise
         except Exception as e:  # ScheduleError, launch failure, numerics
@@ -192,38 +203,46 @@ class TrainingWorkload:
                    self.train_y[j:j + self.batch])
             i += self.batch
 
+    def run(self, program: Program) -> tuple[float, float]:
+        """Train with ``program`` as the step and score the result; any
+        failure propagates as raised (the unpatched program is run this
+        way)."""
+        fn = jit_program(program)
+        weights = {k: jnp.asarray(v) for k, v in self.init_weights.items()}
+        expected_shapes = {k: v.shape for k, v in self.init_weights.items()}
+        t_meas = 0.0
+        batches = self._batches()
+        for step in range(self.steps):
+            x, y = next(batches)
+            y1h = np.eye(self.num_classes, dtype=np.float32)[y]
+            inputs = dict(weights)
+            inputs["x"] = x
+            inputs["y_onehot"] = y1h
+            if self.time_mode == "measured" and step == 1:
+                t_meas = measured_time(fn, inputs) * self.steps
+            outs = fn(inputs)
+            if len(outs) != len(self.weight_names):
+                raise InvalidVariant("variant lost weight outputs")
+            for k, o in zip(self.weight_names, outs):
+                if tuple(o.shape) != expected_shapes[k]:
+                    # the variant changed a weight shape: the training
+                    # feedback loop is broken -> invalid individual
+                    raise InvalidVariant(
+                        f"weight {k} shape drifted to {o.shape}")
+                weights[k] = o
+        final = {k: np.asarray(v, np.float32) for k, v in weights.items()}
+        if any(not np.all(np.isfinite(v)) for v in final.values()):
+            raise InvalidVariant("weights diverged to non-finite")
+        error = self.eval_fn(final)
+        t = t_meas if self.time_mode == "measured" else \
+            static_time(program) * self.steps
+        return _check_finite_scalar(t), _check_finite_scalar(error)
+
     def evaluate(self, program: Program) -> tuple[float, float]:
+        """:meth:`run` as the search sees it: a variant that fails to
+        execute is invalid (Section 4.3), whatever the exception."""
         try:
-            fn = jit_program(program)
-            weights = {k: jnp.asarray(v) for k, v in self.init_weights.items()}
-            expected_shapes = {k: v.shape for k, v in self.init_weights.items()}
-            t_meas = 0.0
-            batches = self._batches()
-            for step in range(self.steps):
-                x, y = next(batches)
-                y1h = np.eye(self.num_classes, dtype=np.float32)[y]
-                inputs = dict(weights)
-                inputs["x"] = x
-                inputs["y_onehot"] = y1h
-                if self.time_mode == "measured" and step == 1:
-                    t_meas = measured_time(fn, inputs) * self.steps
-                outs = fn(inputs)
-                if len(outs) != len(self.weight_names):
-                    raise InvalidVariant("variant lost weight outputs")
-                for k, o in zip(self.weight_names, outs):
-                    if tuple(o.shape) != expected_shapes[k]:
-                        # the variant changed a weight shape: the training
-                        # feedback loop is broken -> invalid individual
-                        raise InvalidVariant(
-                            f"weight {k} shape drifted to {o.shape}")
-                    weights[k] = o
-            final = {k: np.asarray(v, np.float32) for k, v in weights.items()}
-            if any(not np.all(np.isfinite(v)) for v in final.values()):
-                raise InvalidVariant("weights diverged to non-finite")
-            error = self.eval_fn(final)
-            t = t_meas if self.time_mode == "measured" else \
-                static_time(program) * self.steps
-            return _check_finite_scalar(t), _check_finite_scalar(error)
+            return self.run(program)
         except InvalidVariant:
             raise
         except Exception as e:

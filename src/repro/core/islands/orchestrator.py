@@ -45,7 +45,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..evaluator import FitnessCache, workload_fingerprint
+from ..evaluator import (FitnessCache, refuse_workers_on_tpu,
+                         workload_fingerprint)
 from ..nsga2 import pareto_front
 from ..search import GevoML, Individual, SearchResult
 from ..serialize import atomic_write_json
@@ -270,6 +271,7 @@ class IslandOrchestrator:
             for _, payload in todo:
                 run_island_epoch(payload)
             return
+        refuse_workers_on_tpu("process-mode islands")
         ctx = mp.get_context("spawn")
         with ctx.Pool(len(todo)) as pool:
             pool.map(run_island_epoch, [p for _, p in todo])

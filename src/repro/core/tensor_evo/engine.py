@@ -49,8 +49,8 @@ from .evaluator import TensorEvaluator
 
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64(True)
 
 
 class TensorGevoML:

@@ -170,7 +170,7 @@ class BatchedFitness:
     # -- jit-side builders ----------------------------------------------------
     def jnp_terms_fn(self):
         """A jit-traceable ``idx -> (time, valid)`` closure (call under
-        ``jax.experimental.enable_x64``)."""
+        ``jax.enable_x64(True)``)."""
         import jax.numpy as jnp
 
         def fn(idx):

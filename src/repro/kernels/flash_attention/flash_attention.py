@@ -22,6 +22,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _precision(dtype):
+    """Matmul precision for operands of ``dtype``.  The TPU's matrix unit
+    rounds f32 operands to bf16 unless asked for full precision (a 1e-2
+    error against the f32 reference on a v5e); bf16 operands are exact in
+    its single pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
                   n_k: int):
@@ -34,11 +42,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (bq, hd)
-    k = k_ref[0, 0].astype(jnp.float32)            # (bk, hd)
-    v = v_ref[0, 0].astype(jnp.float32)            # (bk, hd)
+    # operands go to the matrix unit in their own dtype; products
+    # accumulate, and the softmax runs, in f32
+    q = q_ref[0, 0]                                # (bq, hd)
+    k = k_ref[0, 0]                                # (bk, hd)
+    v = v_ref[0, 0]                                # (bk, hd)
+    prec = _precision(q.dtype)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=prec,
+                            preferred_element_type=jnp.float32) * scale
     if causal:
         qpos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
@@ -51,7 +63,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new[:, None])
     l_new = alpha * l_scr[...] + jnp.sum(p, axis=1)
-    acc_new = acc_scr[...] * alpha[:, None] + jax.lax.dot(p, v)
+    acc_new = acc_scr[...] * alpha[:, None] + jax.lax.dot(
+        p.astype(v.dtype), v, precision=prec,
+        preferred_element_type=jnp.float32)
 
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -65,9 +79,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float | None
                         = None, block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """q, k, v: (B, H, S, hd) (k/v length may differ from q).  Returns
-    (B, H, Sq, hd)."""
+    (B, H, Sq, hd).  ``interpret`` runs the Pallas interpreter instead of
+    compiling to Mosaic (see ``repro.kernels.interpret_mode``)."""
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
     block_q = min(block_q, Sq)

@@ -1,8 +1,7 @@
 """Public jit'd wrapper for the flash-attention kernel.
 
-``interpret`` defaults to True when no TPU is attached (this container), so
-the kernel body executes in Python on CPU for validation; on TPU hosts it
-lowers to Mosaic.
+On the CPU backend the kernel body runs in the Pallas interpreter, for
+validation; on any other backend it lowers to Mosaic.
 """
 
 from __future__ import annotations
@@ -11,11 +10,8 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .flash_attention import flash_attention_fwd
-
-
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
@@ -23,4 +19,4 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_k: int = 128):
     """q, k, v: (B, H, S, hd) -> (B, H, Sq, hd)."""
     return flash_attention_fwd(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=not _on_tpu())
+                               block_k=block_k, interpret=interpret_mode())

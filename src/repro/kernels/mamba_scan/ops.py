@@ -6,15 +6,12 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .mamba_scan import mamba_scan_fwd
-
-
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
 
 
 @partial(jax.jit, static_argnames=("chunk",))
 def mamba_scan(dt, x, A, B, C, *, chunk: int = 64):
     """Selective scan: h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t; y = C.h."""
     return mamba_scan_fwd(dt, x, A, B, C, chunk=chunk,
-                          interpret=not _on_tpu())
+                          interpret=interpret_mode())

@@ -4,11 +4,8 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .rmsnorm import rmsnorm_fwd
-
-
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
 
 
 @partial(jax.jit, static_argnames=("eps", "block_rows"))
@@ -16,5 +13,5 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128):
     """x: (..., d) -> fused rms-normalized x * scale."""
     shape = x.shape
     y = rmsnorm_fwd(x.reshape(-1, shape[-1]), scale, eps=eps,
-                    block_rows=block_rows, interpret=not _on_tpu())
+                    block_rows=block_rows, interpret=interpret_mode())
     return y.reshape(shape)

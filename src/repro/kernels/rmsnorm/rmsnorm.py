@@ -22,8 +22,10 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 128,
-                interpret: bool = True):
-    """x: (rows, d); scale: (d,)."""
+                interpret: bool):
+    """x: (rows, d); scale: (d,).  ``interpret`` runs the Pallas
+    interpreter instead of compiling to Mosaic (see
+    ``repro.kernels.interpret_mode``)."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)
     assert rows % block_rows == 0

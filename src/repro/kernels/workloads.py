@@ -82,33 +82,63 @@ def kernel_space(kernel: str) -> ScheduleSpace:
     return ScheduleSpace.of(f"kernel/{kernel}", _SPACES[kernel])
 
 
-def _inputs(kernel: str, seed: int):
+def model_width_shapes() -> dict[str, dict[str, int]]:
+    """Each kernel at the widths of a model the repo serves: rmsnorm and
+    flash attention at qwen3-0.6b's d_model and heads (4 x 512 and 2048
+    tokens), mamba_scan at falcon-mamba-7b's d_inner and state size (256
+    steps; D=8192 spans several channel tiles).  Those models run in bf16."""
+    from ..configs import get_config
+    qwen, mamba = get_config("qwen3-0.6b"), get_config("falcon-mamba-7b")
+    return {"rmsnorm": {"rows": 4 * 512, "d": qwen.d_model},
+            "flash_attention": {"B": 1, "H": qwen.n_heads, "S": 2048,
+                                "hd": qwen.hd},
+            "mamba_scan": {"Bt": 1, "L": 256, "D": mamba.d_inner,
+                           "N": mamba.ssm_state}}
+
+
+def kernel_inputs(kernel: str, seed: int = 0, shape: dict | None = None,
+                  dtype=jnp.float32) -> dict:
+    """The fixed seeded inputs of ``kernel`` at ``shape`` (default: its
+    evaluation shape in ``SHAPES``) in ``dtype`` (mamba_scan's ``A`` stays
+    f32, as in the models)."""
     k = jax.random.PRNGKey
+    s = shape or SHAPES[kernel]
+
+    def normal(i, dims):
+        return jax.random.normal(k(seed + i), dims).astype(dtype)
+
     if kernel == "rmsnorm":
-        s = SHAPES[kernel]
-        return {"x": jax.random.normal(k(seed), (s["rows"], s["d"])),
-                "scale": jax.random.normal(k(seed + 1), (s["d"],))}
+        return {"x": normal(0, (s["rows"], s["d"])),
+                "scale": normal(1, (s["d"],))}
     if kernel == "flash_attention":
-        s = SHAPES[kernel]
-        shape = (s["B"], s["H"], s["S"], s["hd"])
-        return {"q": jax.random.normal(k(seed), shape),
-                "k": jax.random.normal(k(seed + 1), shape),
-                "v": jax.random.normal(k(seed + 2), shape)}
-    s = SHAPES["mamba_scan"]
+        qkv = (s["B"], s["H"], s["S"], s["hd"])
+        return {"q": normal(0, qkv), "k": normal(1, qkv), "v": normal(2, qkv)}
+    seq = (s["Bt"], s["L"], s["D"])
     return {"dt": jax.nn.softplus(
-                jax.random.normal(k(seed), (s["Bt"], s["L"], s["D"]))),
-            "x": jax.random.normal(k(seed + 1), (s["Bt"], s["L"], s["D"])),
+                jax.random.normal(k(seed), seq)).astype(dtype),
+            "x": normal(1, seq),
             "A": -jnp.exp(jax.random.normal(
                 k(seed + 2), (s["D"], s["N"])) * 0.3),
-            "B": jax.random.normal(k(seed + 3), (s["Bt"], s["L"], s["N"])),
-            "C": jax.random.normal(k(seed + 4), (s["Bt"], s["L"], s["N"]))}
+            "B": normal(3, (s["Bt"], s["L"], s["N"])),
+            "C": normal(4, (s["Bt"], s["L"], s["N"]))}
+
+
+def _full_precision(fn):
+    """``fn`` with its matmuls at full f32 precision: the TPU's default
+    rounds f32 matmul operands to bf16, and an oracle must not be less
+    exact than the kernels it judges."""
+    def run(inputs):
+        with jax.default_matmul_precision("highest"):
+            return fn(inputs)
+    return run
 
 
 def _variant_fn(kernel: str, genome: dict):
-    """The scheduled computation as ``fn(inputs_dict) -> output``."""
+    """The scheduled computation as ``fn(inputs_dict) -> output``.  The
+    ``ref`` implementation is the kernel's oracle itself."""
     if kernel == "rmsnorm":
         if genome["impl"] == "ref":
-            return lambda i: rmsnorm_ref(i["x"], i["scale"])
+            return _full_precision(lambda i: rmsnorm_ref(i["x"], i["scale"]))
         br = genome["block_rows"]
         if genome["epilogue"] == "fused":
             return lambda i: rmsnorm(i["x"], i["scale"], block_rows=br)
@@ -116,20 +146,21 @@ def _variant_fn(kernel: str, genome: dict):
         return lambda i: rmsnorm(i["x"], ones, block_rows=br) * i["scale"]
     if kernel == "flash_attention":
         if genome["impl"] == "ref":
-            return lambda i: attention_ref(i["q"], i["k"], i["v"],
-                                           causal=True)
+            return _full_precision(lambda i: attention_ref(
+                i["q"], i["k"], i["v"], causal=True))
         bq, bk = genome["block_q"], genome["block_k"]
         return lambda i: flash_attention(i["q"], i["k"], i["v"], causal=True,
                                          block_q=bq, block_k=bk)
     if genome["impl"] == "ref":
-        return lambda i: mamba_scan_ref(i["dt"], i["x"], i["A"], i["B"],
-                                        i["C"])
+        return _full_precision(lambda i: mamba_scan_ref(
+            i["dt"], i["x"], i["A"], i["B"], i["C"]))
     ch = genome["chunk"]
     return lambda i: mamba_scan(i["dt"], i["x"], i["A"], i["B"], i["C"],
                                 chunk=ch)
 
 
-def _ref_output(kernel: str, inputs):
+def kernel_reference(kernel: str, inputs) -> np.ndarray:
+    """The kernel's ``ref.py`` oracle on ``inputs``, as f32 numpy."""
     return np.asarray(_variant_fn(kernel, {"impl": "ref"})(inputs),
                       np.float32)
 
@@ -169,8 +200,8 @@ def build_kernel_workload(kernel: str = "rmsnorm", *,
 
     space = kernel_space(kernel)
     shape = SHAPES[kernel]
-    inputs = _inputs(kernel, seed)
-    ref_out = _ref_output(kernel, inputs)
+    inputs = kernel_inputs(kernel, seed)
+    ref_out = kernel_reference(kernel, inputs)
 
     def static_probe(genome: dict) -> float:
         # the exact gate check the runner performs first, exposed for the
@@ -245,8 +276,8 @@ def build_joint_kernel_workload(*, time_mode: str = "static",
     if time_mode != "static":
         raise ValueError("joint workload supports time_mode='static' only")
     space = joint_space()
-    inputs = {k: _inputs(k, seed) for k in KERNELS}
-    refs = {k: _ref_output(k, inputs[k]) for k in KERNELS}
+    inputs = {k: kernel_inputs(k, seed) for k in KERNELS}
+    refs = {k: kernel_reference(k, inputs[k]) for k in KERNELS}
 
     def sub_genome(genome: dict, kernel: str) -> dict:
         return {knob: genome[f"{kernel}.{knob}"]

@@ -18,7 +18,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_smoke_mesh(n_data: int = 2, n_model: int = 2):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
+    """A ``(data, model)`` mesh over the attached devices: the chips of a
+    TPU host, or on the CPU as many XLA host devices
+    (``--xla_force_host_platform_device_count``)."""
     return jax.make_mesh((n_data, n_model), ("data", "model"))
 
 

@@ -19,10 +19,13 @@ writer tag.
       --oneshot --requests 4 --prompt-len 32 --gen 16
 
   # multi-replica serving through the deploy router (optionally sharded
-  # over a smoke mesh; see also `python -m repro.core.deploy.router`)
+  # over a mesh; see also `python -m repro.core.deploy.router`): on the
+  # CPU over XLA host devices, on a four-chip TPU host one replica per chip
   XLA_FLAGS=--xla_force_host_platform_device_count=4 \
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --replicas 2 --mesh 2x2 --requests 8 --prompt-len 16 --gen 6
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
+      --replicas 4 --mesh 4x1 --requests 8 --prompt-len 128 --gen 32
 """
 
 from __future__ import annotations
@@ -52,8 +55,10 @@ def main() -> None:
                          "router (default: the resolved serve plan's "
                          "replicas knob, usually 1)")
     ap.add_argument("--mesh", default=None,
-                    help="DATAxMODEL smoke mesh for the replicas, e.g. "
-                         "2x2 (requires that many XLA host devices)")
+                    help="DATAxMODEL mesh over the attached devices for "
+                         "the replicas, e.g. 4x1 gives each of 4 replicas "
+                         "its own chip (on the CPU: that many XLA host "
+                         "devices)")
     ap.add_argument("--artifacts", default=None,
                     help="ArtifactRegistry directory (serve-schedule and "
                          "plan artifacts)")
@@ -78,6 +83,9 @@ def main() -> None:
     ap.add_argument("--liveloop-ticks", type=int, default=0,
                     help="control-loop ticks to run before serving")
     args = ap.parse_args()
+
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import numpy as np
 

@@ -55,8 +55,11 @@ def main() -> None:
     from ..optim.schedules import cosine_schedule, wsd_schedule
     from ..train.checkpoint import load_latest, restore_like, save_checkpoint
     from ..train.train_step import TrainState, make_train_step
+    from .compile_cache import enable_compile_cache
     from .mesh import make_smoke_mesh
     from .shardings import param_specs, to_shardings
+
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.scale:

@@ -1,34 +1,8 @@
-"""Model configuration shared by all assigned architectures, plus the
-jax-version shard_map compatibility wrapper."""
+"""Model configuration shared by all assigned architectures."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at the
-    top level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the equivalent knob named
-    ``check_rep``.  All repo callsites go through this wrapper."""
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` across jax versions: 0.4.x lacks it, but
-    ``psum(1, axis)`` is statically evaluated to the (concrete) mesh axis
-    size inside shard_map, which is exactly the value callers reshape by."""
-    import jax
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 @dataclass(frozen=True)

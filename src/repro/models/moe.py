@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import ModelConfig, axis_size
+from .common import ModelConfig
 from .layers import dense_init, swiglu
 
 NEG_INF = -1e30
@@ -101,7 +101,7 @@ def moe_ep_a2a_decode(p, cfg: ModelConfig, x, *, expert_axis: str = "model",
     gathering costs — 3 orders of magnitude on the 671B decode cell
     (EXPERIMENTS.md §Perf)."""
     n, d = x.shape
-    m = axis_size(expert_axis)
+    m = jax.lax.axis_size(expert_axis)
     rank = jax.lax.axis_index(expert_axis)
     mine = (jnp.arange(n) % m) == rank
     y = moe_ep_a2a(p, cfg, x, expert_axis=expert_axis,
@@ -170,7 +170,7 @@ def moe_ep_a2a(p, cfg: ModelConfig, x, *, expert_axis: str = "model",
     ``x``: (n_local, d) tokens already local to this shard.  Expert weights
     arrive sharded: (E_pad/M, d, ff) blocks.  Router is replicated."""
     n, d = x.shape
-    m = axis_size(expert_axis)
+    m = jax.lax.axis_size(expert_axis)
     e_local = p["w_gate"].shape[0]
     e_pad = e_local * m
     k = cfg.top_k

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from .attention import (gqa_decode, gqa_forward, init_attn, mla_decode,
                         mla_forward)
-from .common import ModelConfig, shard_map
+from .common import ModelConfig
 from .layers import dense_init, rms_norm, softmax_cross_entropy, swiglu
 from .mamba import (init_mamba, mamba1_decode, mamba1_seq, mamba2_decode,
                     mamba2_seq)
@@ -129,7 +129,7 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
                                       expert_axis=dist.model_axis)
                 return y.reshape(bl, sl, d)
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 local_dec, mesh=dist.mesh,
                 in_specs=(P(dist.batch_axes, None, None), pspec),
                 out_specs=P(dist.batch_axes, None, None), check_vma=False)
@@ -146,7 +146,7 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
                            expert_axis=dist.model_axis)
             return y.reshape(bl, sl, d)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_moe, mesh=dist.mesh,
             in_specs=(P(dist.batch_axes, dist.model_axis, None), pspec),
             out_specs=P(dist.batch_axes, dist.model_axis, None),
@@ -447,3 +447,28 @@ def decode_step(params, token_batch: dict, caches: dict, index,
     h, new_caches = _forward(params, cfg, token_batch, dist,
                              decoding=True, caches=caches, index=index)
     return _head(params, h[:, -1]), new_caches
+
+
+def greedy_reference(params, prompt, gen: int, cfg: ModelConfig):
+    """The serving oracle: prefill one prompt (B=1), then ``gen - 1``
+    lockstep decode steps, greedy.  Returns ``(tokens, logits)`` with the
+    logits of every step.  It shares no code with ``core.deploy``'s
+    engine, which tests and the chip smoke compare against it."""
+    P = len(prompt)
+    logits, pre = prefill(params, {"tokens": jnp.asarray(prompt)[None, :]},
+                          cfg)
+    caches = jax.tree.map(
+        lambda full, x: full.at[:, :, :P].set(x) if full.shape != x.shape
+        else x, init_cache(cfg, 1, P + gen), pre)
+    steps = [logits[0]]
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    tokens = [int(tok[0])]
+    for t in range(gen - 1):
+        tb = {"tokens": tok[:, None],
+              "positions": jnp.full((1, 1), P + t, jnp.int32)}
+        logits, caches = decode_step(params, tb, caches, jnp.int32(P + t),
+                                     cfg)
+        steps.append(logits[0])
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        tokens.append(int(tok[0]))
+    return tokens, steps

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..models.common import shard_map
 from ..models.transformer import Dist, train_loss
 from ..optim.grad_compress import compress_tree_psum
 from ..optim.optimizers import Optimizer
@@ -91,7 +90,7 @@ def make_train_step(cfg, optimizer: Optimizer, dist: Dist = Dist(),
                         jax.tree.map(lambda _: P(dist.batch_axes), batch))
             out_specs = (P(), jax.tree.map(lambda _: P(), params),
                          jax.tree.map(lambda _: P(), params))
-            loss, grads, res = shard_map(
+            loss, grads, res = jax.shard_map(
                 local_grads, mesh=dist.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False)(params, batch)
         elif microbatches > 1:

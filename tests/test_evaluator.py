@@ -153,6 +153,19 @@ def test_parallel_inline_static_short_circuit(tiny_workload, some_patches):
     ev.close()
 
 
+def test_parallel_refuses_workers_on_tpu(tiny_workload, some_patches,
+                                        monkeypatch):
+    """On a TPU the parent already holds the chip: spawning workers that
+    each open JAX must fail loudly, before any worker starts."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ev = ParallelEvaluator(tiny_workload, n_workers=2)
+    with pytest.raises(RuntimeError, match="process that holds the chip"):
+        ev.evaluate_batch(some_patches)
+    assert ev._pool is None
+    ev.close()
+
+
 def test_unpicklable_workload_needs_spec(tiny_workload):
     # TrainingWorkload.eval_fn is a closure: transport must fall back to the
     # WorkloadSpec recipe the builder attached

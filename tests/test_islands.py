@@ -265,6 +265,18 @@ def test_resume_rejects_other_workload(tmp_path, tiny_workload):
         orch.run(generations=4, resume=True)
 
 
+def test_process_mode_refuses_on_tpu(tiny_workload, tmp_path, monkeypatch):
+    """Process-mode islands spawn workers that each need the chip; on a TPU
+    they are refused up front instead of hanging or running elsewhere."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    orch = IslandOrchestrator(tiny_workload, root_dir=str(tmp_path),
+                              n_islands=2, pop_size=6, migrate_every=2,
+                              processes=True)
+    with pytest.raises(RuntimeError, match="process that holds the chip"):
+        orch.run(generations=2)
+
+
 # -- process mode (spawn is slow: slow tier) --------------------------------
 
 @pytest.mark.slow

@@ -41,9 +41,11 @@ def test_flash_attention_cross_length():
 
 @pytest.mark.parametrize("Bt,L,D,N,chunk", [(1, 64, 8, 4, 16),
                                             (2, 128, 16, 8, 32),
-                                            (2, 96, 4, 16, 32)])
+                                            (2, 96, 4, 16, 32),
+                                            (1, 32, 4096, 4, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_mamba_scan_sweep(Bt, L, D, N, chunk, dtype):
+    # D=4096 is wider than one channel tile: two tiles share each chunk
     dt = jax.nn.softplus(_rand(0, (Bt, L, D), jnp.float32)).astype(dtype)
     x = _rand(1, (Bt, L, D), dtype)
     A = -jnp.exp(_rand(2, (D, N), jnp.float32) * 0.3)

@@ -14,7 +14,7 @@ from repro.core.deploy import (Artifact, ArtifactRegistry, ServeEngine,
                                serve_schedule_space)
 from repro.core.evaluator import FitnessCache
 from repro.core.liveloop.traces import demo_requests
-from repro.models.transformer import init_params
+from repro.models.transformer import greedy_reference, init_params
 
 
 @pytest.fixture(scope="module")
@@ -28,46 +28,16 @@ def _prompts(cfg, lens, seed=0):
     return [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in lens]
 
 
-def _direct_generate(cfg, params, prompt: np.ndarray, gen: int
-                     ) -> list[int]:
-    """Engine-independent oracle: the direct models.transformer prefill +
-    lockstep decode_step loop (the pre-ServeEngine launcher's algorithm),
-    B=1, greedy.  Deliberately shares NO code with core.deploy.engine."""
-    import jax.numpy as jnp
-
-    from repro.models.transformer import (decode_step, init_cache, prefill)
-    P, G = len(prompt), gen
-    batch = {"tokens": jnp.asarray(prompt[None, :])}
-    logits, pre_caches = prefill(params, batch, cfg)
-    caches = init_cache(cfg, 1, P + G)
-
-    def splice(full, pre):
-        if full.ndim >= 3 and pre.ndim == full.ndim and \
-                pre.shape[2] == P and full.shape[2] == P + G:
-            return full.at[:, :, :P].set(pre)
-        return pre if pre.shape == full.shape else full
-    caches = jax.tree.map(splice, caches, pre_caches)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    out = [int(tok[0])]
-    for t in range(G - 1):
-        tb = {"tokens": tok[:, None],
-              "positions": jnp.full((1, 1), P + t, jnp.int32)}
-        logits, caches = decode_step(params, tb, caches, jnp.int32(P + t),
-                                     cfg)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        out.append(int(tok[0]))
-    return out
-
-
 class TestEngineCorrectness:
     def test_engine_matches_direct_model_loop(self, qwen):
         """The engine (continuous batching, lane caches, vmapped decode)
         must be bit-identical to the direct models.transformer
-        prefill/decode loop — an oracle that shares no serving code."""
+        prefill/decode loop (``greedy_reference``) — an oracle that shares
+        no serving code."""
         cfg, params = qwen
         prompts = _prompts(cfg, (8, 4, 8), seed=9)
         gen = 5
-        refs = [_direct_generate(cfg, params, p, gen) for p in prompts]
+        refs = [greedy_reference(params, p, gen, cfg)[0] for p in prompts]
         eng = ServeEngine(cfg, params, max_len=16, max_slots=2,
                           prefill_chunk=1)
         reqs = [ServeRequest(uid=f"r{i}", tokens=p, max_new_tokens=gen)

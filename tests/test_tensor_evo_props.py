@@ -122,10 +122,9 @@ def test_jnp_backend_agrees_with_python():
     path agrees with the scalar engine on these populations too."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(3)
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(lambda o, v: rank_crowd(o, v, xp=jnp))
         for _ in range(25):
             objs = _random_objs(rng)
