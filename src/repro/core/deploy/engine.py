@@ -53,6 +53,7 @@ import numpy as np
 
 from ..evaluator import EvalOutcome, FitnessCache
 from ..schedule import ScheduleSpace
+from ..spans import OFF, Spans
 from .kvplan import DEFAULT_KV_PLAN, KV_SPACE, KVPlan
 from .registry import Artifact, shape_tag
 
@@ -201,12 +202,17 @@ def _jitted(cfg):
     import jax
 
     from ...models.transformer import decode_step, prefill
-    pre = jax.jit(lambda p, b: prefill(p, b, cfg))
-    dec = jax.jit(
-        jax.vmap(lambda p, tb, c, i: decode_step(p, tb, c, i, cfg),
-                 in_axes=(None, 0, 1, 0), out_axes=(0, 1)),
-        donate_argnums=(2,))
-    return pre, dec
+
+    # named functions: a profiler trace shows the programs as
+    # ``jit_serve_prefill`` and ``jit_serve_decode``
+    def serve_prefill(p, b):
+        return prefill(p, b, cfg)
+
+    def serve_decode(p, tb, c, i):
+        return decode_step(p, tb, c, i, cfg)
+
+    dec = jax.vmap(serve_decode, in_axes=(None, 0, 1, 0), out_axes=(0, 1))
+    return jax.jit(serve_prefill), jax.jit(dec, donate_argnums=(2,))
 
 
 def _stack_lanes(caches: list[dict]):
@@ -249,13 +255,18 @@ class ServeEngine:
     grouping below prefers same-length prefill batches, but any request
     queued longer than this many ticks forces strict oldest-first
     admission, so an odd-length prompt can never be starved behind a
-    steady stream of grouping-friendly ones."""
+    steady stream of grouping-friendly ones.
+
+    ``spans`` (a :class:`~repro.core.spans.Spans`) records the tick from
+    inside: ``tick``, ``admit`` with its ``prefill`` and ``splice``,
+    ``dispatch``, ``fetch`` (every wait for sampled ids), the ``decode`` and
+    ``queue`` intervals and the ``prefill_tokens`` count.  Off by default."""
 
     def __init__(self, cfg, params=None, *, max_len: int = 128,
                  max_slots: int = 4, prefill_chunk: int = 2,
                  evolved_cfg=None, ab_fraction: float = 0.0,
                  temperature: float = 0.0, seed: int = 0,
-                 admit_max_wait: int = 32):
+                 admit_max_wait: int = 32, spans: Spans | None = None):
         import jax
         if cfg.family == "encoder":
             raise ValueError("encoder-only arch has no decode step")
@@ -287,9 +298,14 @@ class ServeEngine:
         self.n_ticks = 0
         self.n_prefill_batches = 0
         self.n_decode_batches = 0
+        self.spans = OFF if spans is None else spans
+        self._t_tick = self._t_dispatch = None
 
     # -- submission ----------------------------------------------------------
-    def submit(self, req: ServeRequest) -> None:
+    def submit(self, req: ServeRequest, *,
+               t_submit: float | None = None) -> None:
+        """Queue ``req``; ``t_submit`` is when it was accepted, if earlier
+        than now (a router's own queue)."""
         tokens = np.asarray(req.tokens, np.int32).reshape(-1)
         if len(tokens) + req.max_new_tokens > self.max_len:
             raise ValueError(
@@ -300,7 +316,8 @@ class ServeEngine:
             raise ValueError(f"request {req.uid}: unknown variant "
                              f"{req.variant!r} (have {list(self.cfgs)})")
         req.tokens = tokens
-        req._t_submit = _time.perf_counter()
+        req._t_submit = _time.perf_counter() if t_submit is None \
+            else t_submit
         req._enq_tick = self.n_ticks
         self.queue.append(req)
 
@@ -376,14 +393,19 @@ class ServeEngine:
         return take
 
     def _admit(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from ...models.transformer import init_cache
         n_free = self.max_slots - self._n_in_flight()
         n_take = min(n_free, self.prefill_chunk, len(self.queue))
         if n_take <= 0:
             return
+        with self.spans.span("admit"):
+            self._admit_some(n_take)
+
+    def _admit_some(self, n_take: int) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        from ...models.transformer import init_cache
+        spans = self.spans
         admitted = self._select_admissions(n_take)
         t_admit = _time.perf_counter()
         groups: dict[tuple, list[ServeRequest]] = {}
@@ -398,44 +420,51 @@ class ServeEngine:
             toks = np.stack([r.tokens for r in reqs])
             pos = np.broadcast_to(np.arange(plen, dtype=np.int32)[None],
                                   (G, plen))
-            logits, pre_caches = pre_fn(self.params,
-                                        self._token_batch(cfg, toks, pos))
-            self.n_prefill_batches += 1
-            first = self._sample(logits)
+            with spans.span("prefill"):
+                spans.count("prefill_tokens", G * plen)
+                logits, pre_caches = pre_fn(
+                    self.params, self._token_batch(cfg, toks, pos))
+                self.n_prefill_batches += 1
+                first = self._sample(logits)
             t_first = _time.perf_counter()
-            if batch.caches is None:
-                batch.caches = _stack_lanes(
-                    [init_cache(cfg, 1, self.max_len)] * batch.n_lanes)
-            free = batch.free_lanes()
-            # shapes only: the padded cache is built where the prefill cache
-            # lives (the replica's own device), not on the default device
-            full = jax.eval_shape(lambda: init_cache(cfg, 1, self.max_len))
-            for i, req in enumerate(reqs):
-                mine = _batch_axis_slice(pre_caches, i)
+            with spans.span("splice"):
+                if batch.caches is None:
+                    batch.caches = _stack_lanes(
+                        [init_cache(cfg, 1, self.max_len)] * batch.n_lanes)
+                free = batch.free_lanes()
+                # shapes only: the padded cache is built where the prefill
+                # cache lives (the replica's own device), not on the
+                # default device
+                full = jax.eval_shape(
+                    lambda: init_cache(cfg, 1, self.max_len))
+                for i, req in enumerate(reqs):
+                    mine = _batch_axis_slice(pre_caches, i)
 
-                def splice(f, p, _plen=plen):
-                    if p.shape == f.shape:
-                        return p
-                    if (f.ndim >= 3 and p.ndim == f.ndim
-                            and p.shape[2] == _plen
-                            and f.shape[2] == self.max_len):
-                        pad = [(0, 0)] * f.ndim
-                        pad[2] = (0, self.max_len - _plen)
-                        return jnp.pad(p.astype(f.dtype), pad)
-                    raise ValueError(f"prefill cache leaf {p.shape} does "
-                                     f"not fit decode cache {f.shape}")
-                one = jax.tree.map(splice, full, mine)
-                tok = int(first[i])
-                res = ServeResult(
-                    uid=req.uid, variant=variant,
-                    t_submit=getattr(req, "_t_submit", t_admit),
-                    t_admit=t_admit, t_first=t_first)
-                lane = _Lane(req=req, index=plen, tokens=[tok], last=tok,
-                             res=res)
-                if not self._maybe_finish(lane, t_first):
-                    li = free.pop(0)
-                    batch.lanes[li] = lane
-                    batch.caches = _write_lane(batch.caches, li, one)
+                    def splice(f, p, _plen=plen):
+                        if p.shape == f.shape:
+                            return p
+                        if (f.ndim >= 3 and p.ndim == f.ndim
+                                and p.shape[2] == _plen
+                                and f.shape[2] == self.max_len):
+                            pad = [(0, 0)] * f.ndim
+                            pad[2] = (0, self.max_len - _plen)
+                            return jnp.pad(p.astype(f.dtype), pad)
+                        raise ValueError(f"prefill cache leaf {p.shape} "
+                                         f"does not fit decode cache "
+                                         f"{f.shape}")
+                    one = jax.tree.map(splice, full, mine)
+                    tok = int(first[i])
+                    res = ServeResult(
+                        uid=req.uid, variant=variant,
+                        t_submit=getattr(req, "_t_submit", t_admit),
+                        t_admit=t_admit, t_first=t_first)
+                    spans.record("queue", res.t_submit, res.t_admit)
+                    lane = _Lane(req=req, index=plen, tokens=[tok],
+                                 last=tok, res=res)
+                    if not self._maybe_finish(lane, t_first):
+                        li = free.pop(0)
+                        batch.lanes[li] = lane
+                        batch.caches = _write_lane(batch.caches, li, one)
 
     # -- decode --------------------------------------------------------------
     def _sample(self, logits):
@@ -443,9 +472,11 @@ class ServeEngine:
         import jax.numpy as jnp
         if self.temperature > 0:
             self._sample_key, sub = jax.random.split(self._sample_key)
-            return np.asarray(jax.random.categorical(
-                sub, logits / self.temperature)).astype(np.int32)
-        return np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
+            ids = jax.random.categorical(sub, logits / self.temperature)
+        else:
+            ids = jnp.argmax(logits, -1)
+        with self.spans.span("fetch"):
+            return np.asarray(ids).astype(np.int32)
 
     def _decode_dispatch(self) -> list[tuple]:
         """Phase 1 of a decode tick: launch ONE vmapped decode dispatch per
@@ -453,6 +484,10 @@ class ServeEngine:
         logits)`` work items *without* blocking on the results — a router
         interleaves dispatches across replicas so each replica's compute
         overlaps its siblings' host work."""
+        with self.spans.span("dispatch") as self._t_dispatch:
+            return self._dispatch_variants()
+
+    def _dispatch_variants(self) -> list[tuple]:
         pending = []
         for variant in sorted(self.batches):
             batch = self.batches[variant]
@@ -488,6 +523,7 @@ class ServeEngine:
             batch = self.batches[variant]
             nxt = self._sample(logits[:, 0])
             t_now = _time.perf_counter()
+            self.spans.record("decode", self._t_dispatch, t_now)
             for i, lane in active:
                 lane.index += 1
                 tok = int(nxt[i])
@@ -521,6 +557,7 @@ class ServeEngine:
         Callers that drive several engines — the multi-replica router —
         begin every replica's step before finishing any, so device compute
         overlaps across replicas."""
+        self._t_tick = self.spans.now()
         if self._t0 is None:
             self._t0 = _time.perf_counter()
         self.n_ticks += 1
@@ -531,6 +568,7 @@ class ServeEngine:
         """The second half of a tick: block on the dispatched decode,
         sample, and retire finished lanes."""
         self._decode_complete(pending)
+        self.spans.record("tick", self._t_tick, self.spans.now())
 
     def step(self) -> None:
         """One engine tick: admit + micro-batch prefill new requests, then

@@ -44,6 +44,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..evaluator import EvalOutcome, FitnessCache
+from ..spans import Spans
 from .engine import DEFAULT_SERVE_PLAN, ServeEngine, ServeRequest, \
     _stack_lanes
 from .kvplan import KVPlan
@@ -136,6 +137,7 @@ class Router:
             raise ValueError(f"request {req.uid}: unknown variant "
                              f"{req.variant!r} (have {list(variants)})")
         req.tokens = tokens
+        req._t_submit = _time.perf_counter()
         self.queue.append(req)
 
     def try_submit(self, req: ServeRequest) -> bool:
@@ -161,7 +163,7 @@ class Router:
             r = min(live, key=lambda x: (len(x.engine.queue)
                                          + x.engine._n_in_flight(),
                                          x.index))
-            r.engine.submit(req)
+            r.engine.submit(req, t_submit=req._t_submit)
 
     # -- the loop ----------------------------------------------------------
     def step(self) -> None:
@@ -371,14 +373,19 @@ def build_router(cfg, params=None, *, genome: dict | None = None,
                  max_len: int = 128, mesh=None, evolved_cfg=None,
                  ab_fraction: float = 0.0, temperature: float = 0.0,
                  seed: int = 0, admit_max_wait: int = 32,
-                 heartbeat_timeout: float = 8.0) -> Router:
+                 heartbeat_timeout: float = 8.0,
+                 spans: Spans | None = None) -> Router:
     """Resolve a serve-plan genome into a running multi-replica router.
 
     The genome's ``replicas`` knob picks the fan-out; its KV plan clamps
     each replica's ``max_slots`` to what the plan's pages fit
     (:meth:`KVPlan.effective_slots`).  With ``mesh`` given (e.g.
     ``make_smoke_mesh()``), the mesh's data rows are split across replicas
-    and each replica's params + decode caches are sharded over its row."""
+    and each replica's params + decode caches are sharded over its row.
+    ``spans`` is every replica's recorder (see :class:`ServeEngine`).
+    The replicas' intervals interleave: one replica's ``tick`` and
+    ``decode`` also cover its siblings' work between its ``begin_step``
+    and its ``finish_step``."""
     import jax
     g = dict(DEFAULT_SERVE_PLAN, **(genome or {}))
     plan = KVPlan.from_genome(g)
@@ -395,7 +402,7 @@ def build_router(cfg, params=None, *, genome: dict | None = None,
                           prefill_chunk=int(g["prefill_chunk"]),
                           evolved_cfg=evolved_cfg, ab_fraction=ab_fraction,
                           temperature=temperature, seed=seed + i,
-                          admit_max_wait=admit_max_wait)
+                          admit_max_wait=admit_max_wait, spans=spans)
         if sm is not None:
             shard_engine_caches(eng, sm)
         engines.append(eng)
