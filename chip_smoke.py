@@ -137,7 +137,7 @@ def _checked_engine_cls():
         def _decode_complete(self, pending):
             for _, active, logits in pending:
                 for i, lane in active:
-                    self._keep(lane.req.uid, logits[i, 0])
+                    self._keep(lane.req.uid, logits[i])
             super()._decode_complete(pending)
 
     return CheckedEngine

@@ -167,10 +167,11 @@ class _Lane:
 
 class _LaneBatch:
     """A variant's fixed-width continuous batch: ``n_lanes`` resident
-    sequences sharing ONE stacked cache (lane axis 1), advanced by a single
-    vmapped decode dispatch per tick with a per-lane cache index.  Lane
-    shapes never change, so decode compiles exactly once per variant; a
-    finished lane's cache is simply overwritten at the next admission."""
+    sequences sharing ONE cache whose batch axis is the lane
+    (``init_cache(cfg, n_lanes, max_len)``), advanced by a single decode
+    dispatch per tick with a per-lane cache index.  Lane shapes never
+    change, so decode compiles exactly once per variant; a finished lane's
+    cache is simply overwritten at the next admission."""
 
     def __init__(self, n_lanes: int):
         self.n_lanes = n_lanes
@@ -195,10 +196,11 @@ class _LaneBatch:
 
 @lru_cache(maxsize=32)
 def _jitted(cfg):
-    """(prefill, decode) jitted for ``cfg``.  Decode is **vmapped over
-    lanes with a per-lane cache index**: all in-flight sequences advance in
-    ONE fixed-shape dispatch regardless of their (different) positions —
-    the core continuous-batching capability the lockstep path lacks."""
+    """(prefill, decode) jitted for ``cfg``.  Decode takes **a per-lane
+    cache index**: all in-flight sequences advance in ONE fixed-shape
+    dispatch regardless of their (different) positions — the core
+    continuous-batching capability the lockstep path lacks.  The lane cache
+    is donated and each lane's new K/V rows are written into it in place."""
     import jax
 
     from ...models.transformer import decode_step, prefill
@@ -211,24 +213,16 @@ def _jitted(cfg):
     def serve_decode(p, tb, c, i):
         return decode_step(p, tb, c, i, cfg)
 
-    dec = jax.vmap(serve_decode, in_axes=(None, 0, 1, 0), out_axes=(0, 1))
-    return jax.jit(serve_prefill), jax.jit(dec, donate_argnums=(2,))
+    return jax.jit(serve_prefill), jax.jit(serve_decode, donate_argnums=(2,))
 
 
-def _stack_lanes(caches: list[dict]):
-    """Per-sequence (B=1) caches stacked on a new lane axis (axis 1)."""
+def _write_lane(lanes: dict, lane: int, one: dict):
+    """Install one sequence's (B=1) cache into lane ``lane`` of the lane
+    batch's cache (a device-side single-lane copy; the only per-admission
+    cache traffic — decode itself writes one token per lane)."""
     import jax
-    import jax.numpy as jnp
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *caches)
-
-
-def _write_lane(stacked: dict, lane: int, one: dict):
-    """Install one sequence's (B=1) cache into lane ``lane`` of the stacked
-    batch (a device-side single-lane copy; the only per-admission cache
-    traffic — decode itself never restacks)."""
-    import jax
-    return jax.tree.map(lambda full, x: full.at[:, lane].set(x),
-                        stacked, one)
+    return jax.tree.map(lambda full, x: full.at[:, lane].set(x[:, 0]),
+                        lanes, one)
 
 
 def _batch_axis_slice(caches: dict, i: int):
@@ -429,8 +423,8 @@ class ServeEngine:
             t_first = _time.perf_counter()
             with spans.span("splice"):
                 if batch.caches is None:
-                    batch.caches = _stack_lanes(
-                        [init_cache(cfg, 1, self.max_len)] * batch.n_lanes)
+                    batch.caches = init_cache(cfg, batch.n_lanes,
+                                              self.max_len)
                 free = batch.free_lanes()
                 # shapes only: the padded cache is built where the prefill
                 # cache lives (the replica's own device), not on the
@@ -479,7 +473,7 @@ class ServeEngine:
             return np.asarray(ids).astype(np.int32)
 
     def _decode_dispatch(self) -> list[tuple]:
-        """Phase 1 of a decode tick: launch ONE vmapped decode dispatch per
+        """Phase 1 of a decode tick: launch ONE decode dispatch per
         active variant and return the in-flight ``(variant, active,
         logits)`` work items *without* blocking on the results — a router
         interleaves dispatches across replicas so each replica's compute
@@ -496,21 +490,19 @@ class ServeEngine:
                 continue
             cfg = self.cfgs[variant]
             _, dec_fn = _jitted(cfg)
-            # ONE fixed-shape vmapped dispatch over every lane of this
-            # variant (idle lanes run at index 0 and are ignored; their
-            # cache is rewritten wholesale at the next admission)
+            # ONE fixed-shape dispatch over every lane of this variant
+            # (idle lanes run at index 0 and are ignored; their cache is
+            # rewritten wholesale at the next admission)
             N = batch.n_lanes
-            toks = np.zeros((N, 1, 1), np.int32)
-            pos = np.zeros((N, 1, 1), np.int32)
+            toks = np.zeros((N, 1), np.int32)
             idx = np.zeros((N,), np.int32)
             for i, lane in active:
-                toks[i, 0, 0] = lane.last
-                pos[i, 0, 0] = lane.index
+                toks[i, 0] = lane.last
                 idx[i] = lane.index
+            pos = idx[:, None]
             tb = {"tokens": toks, "positions": pos}
             if cfg.mrope:
-                tb["positions3"] = np.broadcast_to(pos[..., None],
-                                                   (N, 1, 1, 3))
+                tb["positions3"] = np.broadcast_to(pos[..., None], (N, 1, 3))
             logits, batch.caches = dec_fn(self.params, tb, batch.caches, idx)
             self.n_decode_batches += 1
             pending.append((variant, active, logits))
@@ -521,7 +513,7 @@ class ServeEngine:
         host blocks on device results) and advance lane bookkeeping."""
         for variant, active, logits in pending:
             batch = self.batches[variant]
-            nxt = self._sample(logits[:, 0])
+            nxt = self._sample(logits)
             t_now = _time.perf_counter()
             self.spans.record("decode", self._t_dispatch, t_now)
             for i, lane in active:

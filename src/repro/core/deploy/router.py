@@ -45,8 +45,7 @@ import numpy as np
 
 from ..evaluator import EvalOutcome, FitnessCache
 from ..spans import Spans
-from .engine import DEFAULT_SERVE_PLAN, ServeEngine, ServeRequest, \
-    _stack_lanes
+from .engine import DEFAULT_SERVE_PLAN, ServeEngine, ServeRequest
 from .kvplan import KVPlan
 from .registry import shape_tag
 
@@ -350,23 +349,22 @@ def shard_replica_params(params, submesh):
 
 
 def shard_engine_caches(engine: ServeEngine, submesh) -> None:
-    """Pre-allocate every variant's stacked lane cache sharded over the
-    replica's submesh per ``cache_specs`` (the stacked lane axis is the
-    cache batch dim), so decode runs sharded from the first tick instead of
-    inheriting placement from the first admission."""
+    """Pre-allocate every variant's lane cache sharded over the replica's
+    submesh per ``cache_specs`` (the lanes are the cache's batch dim), so
+    decode runs sharded from the first tick instead of inheriting placement
+    from the first admission."""
     import jax
 
     from ...launch.shardings import cache_specs, to_shardings
     from ...models.transformer import init_cache
     dp_axes, model_axis, dp_size, model_size = _mesh_sizes(submesh)
     for variant, cfg in engine.cfgs.items():
-        stacked = _stack_lanes([init_cache(cfg, 1, engine.max_len)]
-                               * engine.max_slots)
-        specs = cache_specs(cfg, stacked, dp_axes=dp_axes,
+        lanes = init_cache(cfg, engine.max_slots, engine.max_len)
+        specs = cache_specs(cfg, lanes, dp_axes=dp_axes,
                             model_axis=model_axis, dp_size=dp_size,
                             model_size=model_size)
         engine.batches[variant].caches = jax.device_put(
-            stacked, to_shardings(submesh, specs))
+            lanes, to_shardings(submesh, specs))
 
 
 def build_router(cfg, params=None, *, genome: dict | None = None,

@@ -200,18 +200,35 @@ def gqa_forward(p, cfg: ModelConfig, x, positions, dist=None):
     return y, (k, v)
 
 
-def gqa_decode(p, cfg: ModelConfig, x, cache_k, cache_v, index, positions):
-    """One-token decode against a (B, S_max, K, hd) KV cache.
+def _write_token(cache, layer, index, new):
+    """Write one token's rows into a stacked (L, B, T, ...) cache in place:
+    ``new[b, 0]`` lands at ``(layer, b, index[b])``.  Only B rows move, so
+    a donated cache carried through the layer scan is never copied."""
+    rows = jnp.arange(cache.shape[1])
+    return cache.at[layer, rows, index].set(new[:, 0].astype(cache.dtype),
+                                            unique_indices=True)
 
-    ``index`` is the current length (scalar int32); the new token's K/V are
-    written at ``index`` and attention spans positions <= index."""
-    q, k, v = _project_qkv(p, cfg, x, positions)           # S == 1
-    cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k, index, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v, index, axis=1)
-    T = cache_k.shape[1]
+
+def _decode_mask(index, T):
+    """(B, 1, T): row b attends positions <= index[b]."""
     kj = jax.lax.broadcasted_iota(jnp.int32, (1, 1, T), 2)
-    mask = kj <= index
-    out = _sdpa(q, cache_k, cache_v, mask, 1.0 / np.sqrt(cfg.hd))
+    return kj <= index[:, None, None]
+
+
+def gqa_decode(p, cfg: ModelConfig, x, cache_k, cache_v, layer, index,
+               positions):
+    """One-token decode against layer ``layer`` of stacked (L, B, S_max, K,
+    hd) KV caches.
+
+    ``index`` (B,) is each row's current length; the new token's K/V are
+    written at ``index[b]`` and row b attends positions <= index[b].
+    Returns ``(y, (cache_k, cache_v))`` with the whole stacks updated."""
+    q, k, v = _project_qkv(p, cfg, x, positions)           # S == 1
+    cache_k = _write_token(cache_k, layer, index, k)
+    cache_v = _write_token(cache_v, layer, index, v)
+    mask = _decode_mask(index, cache_k.shape[2])
+    out = _sdpa(q, cache_k[layer], cache_v[layer], mask,
+                1.0 / np.sqrt(cfg.hd))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, (cache_k, cache_v)
 
@@ -257,10 +274,12 @@ def mla_forward(p, cfg: ModelConfig, x, positions, dist=None):
     return y, (c_kv, k_rope[..., 0, :])
 
 
-def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, index,
-               positions):
+def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, layer,
+               index, positions):
     """Absorbed-weight MLA decode: attention runs in the compressed
-    kv_lora space, so the cache is (B, S, r_kv) + (B, S, rope) only."""
+    kv_lora space, so the cache is (B, S, r_kv) + (B, S, rope) only, here
+    layer ``layer`` of stacks with a leading layer dim (``index`` as in
+    :func:`gqa_decode`)."""
     nope, rope, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
     q = rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -275,17 +294,16 @@ def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, index,
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
     k_rope = apply_rope(k_rope[..., None, :], positions,
                         cfg.rope_theta)[..., 0, :]
-    cache_ckv = jax.lax.dynamic_update_slice_in_dim(cache_ckv, c_kv, index, 1)
-    cache_krope = jax.lax.dynamic_update_slice_in_dim(cache_krope, k_rope,
-                                                      index, 1)
-    T = cache_ckv.shape[1]
-    logits = (jnp.einsum("bshr,btr->bhst", q_abs, cache_ckv)
-              + jnp.einsum("bshk,btk->bhst", q_rope, cache_krope))
+    cache_ckv = _write_token(cache_ckv, layer, index, c_kv)
+    cache_krope = _write_token(cache_krope, layer, index, k_rope)
+    ckv = cache_ckv[layer]
+    logits = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)
+              + jnp.einsum("bshk,btk->bhst", q_rope, cache_krope[layer]))
     logits = logits.astype(jnp.float32) / np.sqrt(nope + rope)
-    kj = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, T), 3)
-    logits = jnp.where(kj <= index, logits, NEG_INF)
+    mask = _decode_mask(index, ckv.shape[1])
+    logits = jnp.where(mask[:, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, -1).astype(x.dtype)
-    ctx = jnp.einsum("bhst,btr->bshr", probs, cache_ckv)
+    ctx = jnp.einsum("bhst,btr->bshr", probs, ckv)
     # un-absorb the value projection
     out = jnp.einsum("bshr,rhk->bshk", ctx, p["wkv_b"][..., nope:])
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

@@ -160,19 +160,19 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
 # --------------------------------------------------------------------------
 
 def _attn_layer_fwd(lp, cfg, x, positions, dist, decoding=False,
-                    cache=None, index=None):
+                    cache=None, index=None, layer=None):
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.mla:
         if decoding:
             a, new_cache = mla_decode(lp["attn"], cfg, h, cache[0], cache[1],
-                                      index, positions)
+                                      layer, index, positions)
         else:
             a, new_cache = mla_forward(lp["attn"], cfg, h, positions,
                                        dist=dist)
     else:
         if decoding:
             a, new_cache = gqa_decode(lp["attn"], cfg, h, cache[0], cache[1],
-                                      index, positions)
+                                      layer, index, positions)
         else:
             a, new_cache = gqa_forward(lp["attn"], cfg, h, positions,
                                        dist=dist)
@@ -252,28 +252,29 @@ def _embed(params, cfg: ModelConfig, batch: dict):
 
 def _stack_attn(params, cfg, x, positions, dist, decoding=False,
                 caches=None, index=None):
-    """scan over stacked attention-family layers."""
-    mla = cfg.mla
-
-    def body(carry, xs):
-        h = carry
-        if decoding:
-            lp, c0, c1 = xs
-            cache = (c0, c1)
-        else:
-            lp, cache = xs, None
-        out, new_cache = _attn_layer_fwd(lp, cfg, h, positions, dist,
-                                         decoding, cache, index)
-        return out, new_cache
-
-    fn = jax.checkpoint(body) if cfg.remat == "full" and not decoding else body
+    """scan over stacked attention-family layers.  Decoding carries the
+    whole stacked caches through the scan, and each layer writes its new
+    K/V rows into them in place: the caches are never rebuilt as scan
+    outputs, so a donated cache is updated without a copy."""
+    names = ("ckv", "krope") if cfg.mla else ("k", "v")
     if decoding:
-        names = ("ckv", "krope") if mla else ("k", "v")
-        xs = (params["layers"], caches[names[0]], caches[names[1]])
-        x, (nc0, nc1) = lax.scan(fn, x, xs)
-        return x, {names[0]: nc0, names[1]: nc1}
+        def dec_body(carry, xs):
+            h, c0, c1 = carry
+            lp, layer = xs
+            h, (c0, c1) = _attn_layer_fwd(lp, cfg, h, positions, dist, True,
+                                          (c0, c1), index, layer)
+            return (h, c0, c1), None
+
+        (x, c0, c1), _ = lax.scan(
+            dec_body, (x, caches[names[0]], caches[names[1]]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
+        return x, {names[0]: c0, names[1]: c1}
+
+    def body(carry, lp):
+        return _attn_layer_fwd(lp, cfg, carry, positions, dist)
+
+    fn = jax.checkpoint(body) if cfg.remat == "full" else body
     x, (nc0, nc1) = lax.scan(fn, x, params["layers"])
-    names = ("ckv", "krope") if mla else ("k", "v")
     return x, {names[0]: nc0, names[1]: nc1}
 
 
@@ -316,11 +317,11 @@ def _stack_hybrid(params, cfg, x, positions, dist, decoding=False,
     layers_g = jax.tree.map(regroup, params["layers"])
 
     def group_body(carry, xs):
-        h = carry
-        if decoding:
-            lp_g, conv_g, ssm_g, sk, sv = xs
+        if decoding:  # shared K/V stacks ride in the carry (in-place writes)
+            h, sk, sv = carry
+            lp_g, conv_g, ssm_g, g = xs
         else:
-            lp_g = xs
+            h, lp_g = carry, xs
 
         def inner(c, ixs):
             if decoding:
@@ -338,26 +339,28 @@ def _stack_hybrid(params, cfg, x, positions, dist, decoding=False,
         # weight-shared attention + MLP block
         hh = rms_norm(h, shared["ln1"], cfg.norm_eps)
         if decoding:
-            a, (nsk, nsv) = gqa_decode(shared["attn"], cfg, hh, sk, sv,
-                                       index, positions)
+            a, (sk, sv) = gqa_decode(shared["attn"], cfg, hh, sk, sv, g,
+                                     index, positions)
         else:
-            a, (nsk, nsv) = gqa_forward(shared["attn"], cfg, hh, positions,
-                                        dist=dist)
+            a, (sk, sv) = gqa_forward(shared["attn"], cfg, hh, positions,
+                                      dist=dist)
         h = h + a
         hh = rms_norm(h, shared["ln2"], cfg.norm_eps)
         h = h + swiglu(hh, shared["mlp"]["gate"], shared["mlp"]["up"],
                        shared["mlp"]["down"])
-        return h, (nconv, nssm, nsk, nsv)
+        if decoding:
+            return (h, sk, sv), (nconv, nssm)
+        return h, (nconv, nssm, sk, sv)
 
     fn = (jax.checkpoint(group_body)
           if cfg.remat == "full" and not decoding else group_body)
     if decoding:
-        conv_g = regroup(caches["conv"])
-        ssm_g = regroup(caches["ssm"])
-        xs = (layers_g, conv_g, ssm_g, caches["shared_k"], caches["shared_v"])
+        xs = (layers_g, regroup(caches["conv"]), regroup(caches["ssm"]),
+              jnp.arange(G))
+        (x, nsk, nsv), (nconv, nssm) = lax.scan(
+            fn, (x, caches["shared_k"], caches["shared_v"]), xs)
     else:
-        xs = layers_g
-    x, (nconv, nssm, nsk, nsv) = lax.scan(fn, x, xs)
+        x, (nconv, nssm, nsk, nsv) = lax.scan(fn, x, layers_g)
     nconv = nconv.reshape((G * k,) + nconv.shape[2:])
     nssm = nssm.reshape((G * k,) + nssm.shape[2:])
     if rem:  # trailing mamba-only layers
@@ -387,6 +390,8 @@ def _forward(params, cfg: ModelConfig, batch: dict, dist: Dist,
              decoding=False, caches=None, index=None):
     """Returns (final hidden states (B, S, d), new caches)."""
     x, positions = _embed(params, cfg, batch)
+    if decoding:  # one cache length per row: a scalar serves every row
+        index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), x.shape[:1])
     if cfg.family == "ssm":
         x, new_caches = _stack_ssm(params, cfg, x, dist, decoding, caches)
     elif cfg.family == "hybrid":
@@ -443,7 +448,10 @@ def prefill(params, batch: dict, cfg: ModelConfig, dist: Dist = Dist()):
 def decode_step(params, token_batch: dict, caches: dict, index,
                 cfg: ModelConfig, dist: Dist = Dist()):
     """One decode step.  ``token_batch`` holds (B, 1) tokens (or (B,1,d)
-    embeds) plus positions; ``index`` is the current cache length."""
+    embeds) plus positions; ``index`` is the current cache length, a
+    scalar shared by every row or a (B,) vector of each row's own.  Each
+    row's new K/V are written at its index into ``caches`` in place, so a
+    donated cache is updated without a copy."""
     h, new_caches = _forward(params, cfg, token_batch, dist,
                              decoding=True, caches=caches, index=index)
     return _head(params, h[:, -1]), new_caches

@@ -338,6 +338,62 @@ class TestRouterCLI:
         assert recs and all(r["writer"] == "serve" for r in recs)
 
 
+_SHARDED = """
+import json, jax
+from repro.configs import smoke_config
+from repro.core.deploy import build_router, oneshot_generate
+from repro.core.deploy.engine import ServeRequest
+from repro.launch.mesh import make_smoke_mesh
+from repro.models.transformer import init_params
+import numpy as np
+cfg = smoke_config("qwen3-0.6b")
+params = init_params(cfg, jax.random.PRNGKey(0))
+router = build_router(cfg, params, genome={"replicas": 2, "max_slots": 2},
+                      max_len=16, mesh=make_smoke_mesh(2, 2))
+specs = [[str(ax) for ax in b.caches[n].sharding.spec]
+         for r in router.replicas for b in r.engine.batches.values()
+         for n in ("k", "v")]
+rng = np.random.default_rng(3)
+prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in (8, 5, 8)]
+out = {r.uid: r.tokens for r in router.run(
+    [ServeRequest(uid=str(i), tokens=p, max_new_tokens=4)
+     for i, p in enumerate(prompts)], stagger=1)}
+lanes = router.replicas[0].engine.batches["default"].caches
+print(json.dumps({
+    "specs": specs, "shape": list(lanes["k"].shape),
+    "served": [out[str(i)] for i in range(len(prompts))],
+    "oneshot": [oneshot_generate(cfg, params, p[None], 4)[0].tolist()
+                for p in prompts]}))
+"""
+
+
+class TestShardedReplicas:
+    def test_model_axis_lands_on_kv_heads(self):
+        """Two replicas on a 2x2 mesh: each replica's lane cache
+        ``(L, lanes, max_len, kv_heads, hd)`` is split over ``model`` on
+        its KV-head axis, not the sequence axis, and serves the one-shot
+        oracle's tokens."""
+        import os
+        import subprocess
+        import sys
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=src + os.pathsep
+                   + os.environ.get("PYTHONPATH", ""))
+        run = subprocess.run([sys.executable, "-c", _SHARDED], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert run.returncode == 0, run.stderr[-2000:]
+        got = json.loads(run.stdout.strip().splitlines()[-1])
+        cfg = smoke_config("qwen3-0.6b")
+        shape = got["shape"]
+        assert shape == [cfg.n_layers, 2, 16, cfg.n_kv_heads, cfg.hd]
+        assert len(got["specs"]) == 4
+        for spec in got["specs"]:
+            assert spec.index("model") == 3, spec   # the KV-head axis
+        assert got["served"] == got["oneshot"]
+
+
 @pytest.mark.flaky_quarantine
 class TestWallClockThroughput:
     """Real wall-clock throughput comparisons.  Genuinely timing-sensitive

@@ -10,7 +10,7 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.deploy import ServeEngine, ServeRequest, build_router
-from repro.core.deploy.engine import _jitted, _stack_lanes
+from repro.core.deploy.engine import _jitted
 from repro.core.spans import OFF, Spans
 from repro.models.transformer import init_cache, init_params
 
@@ -197,16 +197,17 @@ class TestEngineSpans:
 
 def test_jitted_programs_carry_their_names(qwen):
     """A profiler trace names a program by its module: the prefill and the
-    vmapped decode lower to ``jit_serve_prefill`` and ``jit_serve_decode``."""
+    lane-batch decode lower to ``jit_serve_prefill`` and
+    ``jit_serve_decode``."""
     cfg, params = qwen
     pre, dec = _jitted(cfg)
     plen, n_lanes, max_len = 4, 2, 8
     batch = {"tokens": np.zeros((1, plen), np.int32),
              "positions": np.arange(plen, dtype=np.int32)[None]}
     assert "jit_serve_prefill" in pre.lower(params, batch).as_text()
-    caches = _stack_lanes([init_cache(cfg, 1, max_len)] * n_lanes)
-    tb = {"tokens": np.zeros((n_lanes, 1, 1), np.int32),
-          "positions": np.zeros((n_lanes, 1, 1), np.int32)}
+    caches = init_cache(cfg, n_lanes, max_len)
+    tb = {"tokens": np.zeros((n_lanes, 1), np.int32),
+          "positions": np.zeros((n_lanes, 1), np.int32)}
     idx = np.zeros((n_lanes,), np.int32)
     assert "jit_serve_decode" in dec.lower(params, tb, caches,
                                            idx).as_text()
