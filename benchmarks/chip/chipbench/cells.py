@@ -6,13 +6,26 @@ sits in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` -- sizes, source, the serving plan or search
   settings, and ``entry``: the module under ``entries/`` that drives a run;
-* ``configs/<config>.py``   -- the configuration's plain reference;
+* ``configs/<config>.py``   -- the configuration's plain reference and the
+  work its architecture needs.  A served model's module gives
+  ``make_weights(doc, key, device)``, ``token_gaps(doc, weights, tokens,
+  targets, control)``, ``prefill_flops(doc, prompt_len)``,
+  ``decode_flops(doc, position)`` and ``decode_bytes(doc, positions)``;
+  the serving entry reads no architecture key of the JSON file, only its
+  ``model``, ``serving``, ``check`` and ``entry``;
 * ``traffic/<traffic>.json`` -- the mix's parameters and ``generator``: the
   module under ``traffic/`` that turns them into requests;
 * ``metrics/<metric>.py``   -- a ``read(record)`` that returns one number,
   or ``None`` where the record holds nothing for it.
 
-A later cell, mix or metric is added by adding such files and entries.
+What a reader may read: in a traced run every interval the program's own
+recorder took in the window reaches ``record.program_spans`` and every
+counter it raised there ``record.program_counters``, each under the
+program's name for it, and the device seconds and executions of each named
+program (``jit_<name>``) reach ``record.trace["modules"]``.
+``record.spans`` and ``record.counters`` hold only the benchmark's own
+readings, so no name the program chooses reaches them.  A later cell, mix
+or metric is added by adding such files and entries.
 """
 
 from __future__ import annotations

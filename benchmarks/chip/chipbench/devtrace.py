@@ -18,12 +18,16 @@ import glob
 import gzip
 import os
 import re
+import time
 from collections import defaultdict
 from dataclasses import dataclass, field
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "serve."
+MODULE_HASH = re.compile(r"\(\d+\)$")
 TRACE_SECONDS = 4.0      # the traced sub-window: a few hundred serving steps
 
 
@@ -32,7 +36,10 @@ class Trace:
     """Events of one trace, as plain tuples (name, start_ns, end_ns)."""
 
     ops: dict[int, list[tuple[str, int, int]]] = field(default_factory=dict)
+    modules: dict[int, list[tuple[str, int, int]]] = field(
+        default_factory=dict)
     spans: list[tuple[str, int, int]] = field(default_factory=list)
+    program_spans: list[tuple[str, int, int]] = field(default_factory=list)
 
 
 def op_name(event_name: str) -> str:
@@ -59,12 +66,18 @@ def read_xplane(path: str) -> Trace:
                     (op_name(ev.name), ev.start_ns,
                      ev.start_ns + ev.duration_ns)
                     for ev in line.events)
-            elif not m:
-                tr.spans.extend(
-                    (ev.name[len(SPAN_PREFIX):], ev.start_ns,
+            elif m and line.name == MODULES_LINE:
+                tr.modules.setdefault(int(m.group(1)), []).extend(
+                    (MODULE_HASH.sub("", ev.name), ev.start_ns,
                      ev.start_ns + ev.duration_ns)
-                    for ev in line.events
-                    if ev.name.startswith(SPAN_PREFIX))
+                    for ev in line.events)
+            elif not m:
+                for ev in line.events:
+                    for prefix, out in ((SPAN_PREFIX, tr.spans),
+                                        (PROGRAM_PREFIX, tr.program_spans)):
+                        if ev.name.startswith(prefix):
+                            out.append((ev.name[len(prefix):], ev.start_ns,
+                                        ev.start_ns + ev.duration_ns))
     return tr
 
 
@@ -136,13 +149,21 @@ def reduce(tr: Trace, chips: list[int], top: int = 10) -> dict:
             [(a, b) for _, a, b in evs], lo, hi)))
         for name, ns in _self_times(evs).items():
             self_ns[name] += ns / len(chips)
+    modules: dict[str, list] = {}
+    for c in chips:
+        for name, a, b in tr.modules.get(c, []):
+            if a >= lo and b <= hi:
+                m = modules.setdefault(name, [0, 0.0])
+                m[0] += 1 / len(chips)
+                m[1] += (b - a) / 1e9 / len(chips)
     first = _union([(a, b) for _, a, b in tr.ops.get(chips[0], [])], lo, hi)
     edges = [lo] + [x for ab in first for x in ab] + [hi]
+    named = tr.spans + tr.program_spans
     gaps = []
     for a, b in zip(edges[0::2], edges[1::2]):
         if b > a:
             mid = (a + b) / 2
-            covering = [(e - s, n) for n, s, e in tr.spans if s <= mid < e]
+            covering = [(e - s, n) for n, s, e in named if s <= mid < e]
             gaps.append((min(covering)[1] if covering else "no span",
                          (b - a) / 1e9))
     gaps.sort(key=lambda g: -g[1])
@@ -150,6 +171,7 @@ def reduce(tr: Trace, chips: list[int], top: int = 10) -> dict:
     return {"busy_s": sum(busy_ns) / len(chips) / 1e9,
             "window_s": (hi - lo) / 1e9,
             "device_ops": [[n, ns / 1e9] for n, ns in ops],
+            "modules": modules,
             "idle_gaps": [[n, s] for n, s in gaps[:top]]}
 
 
@@ -157,7 +179,10 @@ class SubWindow:
     """Traces the middle ``trace_s`` seconds of a window of ``seconds`` that
     opened at ``t0``.  The driving loop calls :meth:`poll` between steps,
     so tracing starts and stops only there; :meth:`close` stops a trace
-    still running when the window ends."""
+    still running when the window ends.  :attr:`span` is what the trace
+    holds on the host's clock (``time.perf_counter``): from the return of
+    ``start_trace`` to the call of ``stop_trace``, so that neither call's
+    own stall lies inside it."""
 
     def __init__(self, trace_dir: str | None, t0: float, seconds: float,
                  trace_s: float):
@@ -166,6 +191,15 @@ class SubWindow:
         self.trace_s = trace_s
         self.stop_at: float | None = None
         self.running = False
+        self.started: float | None = None
+        self.stopped: float | None = None
+
+    @property
+    def span(self) -> tuple[float, float] | None:
+        """``(started, stopped)`` once a trace has been taken, else None."""
+        if self.started is None or self.stopped is None:
+            return None
+        return self.started, self.stopped
 
     def poll(self, now: float) -> None:
         import jax
@@ -173,6 +207,7 @@ class SubWindow:
             return
         if self.stop_at is None and now >= self.start_at:
             jax.profiler.start_trace(self.trace_dir)
+            self.started = time.perf_counter()
             self.running, self.stop_at = True, now + self.trace_s
         elif self.running and now >= self.stop_at:
             self.close()
@@ -180,5 +215,6 @@ class SubWindow:
     def close(self) -> None:
         if self.running:
             import jax
+            self.stopped = time.perf_counter()
             jax.profiler.stop_trace()
             self.running = False

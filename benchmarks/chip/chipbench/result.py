@@ -32,7 +32,10 @@ class Check:
 @dataclass
 class Record:
     """What one run measured.  ``end_to_end`` holds values by metric name;
-    the rest is what per-layer readers read."""
+    the rest is what per-layer readers read.  ``spans`` and ``counters``
+    are the benchmark's own; what the program's recorder took in the
+    window is kept apart, in ``program_spans`` and ``program_counters``,
+    so that no name the program chooses can stand in for a yardstick."""
 
     attempted: int
     failed: int
@@ -43,6 +46,8 @@ class Record:
     spans: dict[str, list[float]] = field(default_factory=dict)
     counters: dict[str, float] = field(default_factory=dict)
     trace: dict | None = None          # devtrace.reduce's output
+    program_spans: dict[str, list[float]] = field(default_factory=dict)
+    program_counters: dict[str, float] = field(default_factory=dict)
 
     @property
     def correct(self) -> bool:

@@ -52,6 +52,58 @@ def _shapes(dm: dict) -> dict:
     }
 
 
+# --------------------------------------------------------------------------
+# the work the algorithm needs: operations and bytes
+# --------------------------------------------------------------------------
+#
+# Only what the algorithm needs: attention over the positions a token may see
+# (not a padded cache), the output head where the server computes it (the
+# last prompt position at prefill, every decoded token), and nothing for idle
+# lanes or recomputation.  Multiply-adds count as two operations.
+
+SERVED_BYTES = 2          # bfloat16: the weights' and the KV cache's type
+
+
+def _flop_sizes(doc: dict):
+    dm = _dims(doc)
+    L, d, H, K, hd, ff, V = (dm[k] for k in ("L", "d", "H", "K", "hd", "ff",
+                                             "V"))
+    per_token = 2 * L * (d * H * hd + 2 * d * K * hd + H * hd * d
+                         + 3 * d * ff)
+    return per_token, 2 * d * V, 2 * 2 * L * H * hd
+
+
+def prefill_flops(doc: dict, prompt_len: int) -> float:
+    """A prompt's prefill: every layer over every position, causal
+    attention, the head at the last position."""
+    per_token, head, attn = _flop_sizes(doc)
+    n = prompt_len
+    return float(n * per_token + attn * n * (n + 1) / 2 + head)
+
+
+def decode_flops(doc: dict, position: int) -> float:
+    """One decoded token at ``position`` (0-based), which attends to
+    ``position + 1`` keys."""
+    per_token, head, attn = _flop_sizes(doc)
+    return float(per_token + attn * (position + 1) + head)
+
+
+def decode_bytes(doc: dict, positions) -> int:
+    """Bytes one decode execution must move for the lanes whose processed
+    tokens sit at the 0-based ``positions``: every weight but the embedding
+    table read once, the embedding rows of those tokens, and per lane
+    ``position + 1`` rows of K and V in every layer (``position`` read, one
+    written).  Idle lanes and cache rows past a lane's position are not
+    counted."""
+    dm = _dims(doc)
+    params = sum(int(np.prod(shape)) for path, (shape, _)
+                 in _shapes(dm).items() if path != "embed")
+    kv_row = dm["L"] * 2 * dm["K"] * dm["hd"]
+    rows = sum(int(p) + 1 for p in positions)
+    return SERVED_BYTES * (params + len(positions) * dm["d"]
+                           + rows * kv_row)
+
+
 def _nest(flat: dict) -> dict:
     out: dict = {}
     for path, v in flat.items():

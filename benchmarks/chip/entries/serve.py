@@ -10,6 +10,13 @@ token with the end time of the step that produced it.  After the window,
 arrivals go on and the server steps until every request due in the window
 has finished, or for at most ``GIVE_UP_S``.
 
+A traced run also hands the server the program's own recorder
+(``repro.core.spans.Spans``); its intervals and counters in the window go
+into the record apart from the benchmark's own.  The operations and bytes
+the window's tokens needed come from the configuration's own module
+(``prefill_flops``, ``decode_flops``, ``decode_bytes``): this entry reads
+no architecture key.
+
 The check runs once the window has closed, the memory peak has been read
 and the server is gone: for a sample of finished requests, drawn from the
 seed and holding the one with the most output, the configuration's plain
@@ -25,7 +32,7 @@ import time
 
 import numpy as np
 
-from chipbench import devtrace, flops, timeline
+from chipbench import devtrace, timeline
 from chipbench.result import Check, Record
 from chipbench.runtime import (CompileEvents, Spans, jax_key,
                                memory_peak_bytes, peaks)
@@ -38,8 +45,9 @@ def model_config(doc: dict):
     return ModelConfig(**doc["model"])
 
 
-def build_server(cell, cfg, weights, max_len: int):
-    """The engine, or the router of replicas, that the plan describes."""
+def build_server(cell, cfg, weights, max_len: int, program_spans=None):
+    """The engine, or the router of replicas, that the plan describes;
+    ``program_spans`` is the program's own recorder (traced runs only)."""
     from repro.core.deploy import ServeEngine, build_router
     plan = cell.config["serving"]
     if int(plan.get("replicas", 1)) > 1:
@@ -47,10 +55,12 @@ def build_server(cell, cfg, weights, max_len: int):
         genome = {k: plan[k] for k in ("replicas", "max_slots",
                                        "prefill_chunk")}
         return build_router(cfg, weights, genome=genome, max_len=max_len,
-                            mesh=make_smoke_mesh(*plan["mesh"]))
+                            mesh=make_smoke_mesh(*plan["mesh"]),
+                            spans=program_spans)
     return ServeEngine(cfg, weights, max_len=max_len,
                        max_slots=plan["max_slots"],
-                       prefill_chunk=plan["prefill_chunk"])
+                       prefill_chunk=plan["prefill_chunk"],
+                       spans=program_spans)
 
 
 def engines_of(server) -> list:
@@ -167,19 +177,73 @@ def mean_gap(gaps: list[np.ndarray]) -> float:
     return float(np.concatenate(gaps).mean()) if gaps else float("inf")
 
 
+def window_work(ref, doc: dict, logs: list[timeline.RequestLog],
+                window: tuple[float, float],
+                traced: tuple[float, float]) -> dict:
+    """What the tokens stamped in a span needed, by the counts of the
+    configuration's own module ``ref`` (a request's token 0 is its
+    prefill's, every later one a decode's).  Over the whole ``window``:
+    ``model_flops``.  Over the ``traced`` sub-window, whose device time the
+    trace holds: ``traced_flops`` and its seconds ``traced_s``; of the
+    decoded tokens alone ``decode_flops``; and for the decode executions
+    that produced them, the bytes they had to move (``decode_bytes``) and
+    their number (``decode_steps``).  Tokens of one replica stamped with
+    one time came from one decode execution."""
+    def flops(j: int, r) -> float:
+        return (ref.prefill_flops(doc, r.prompt_len) if j == 0
+                else ref.decode_flops(doc, r.prompt_len + j - 1))
+
+    (t0, t_end), (a, b) = window, traced
+    model_flops = traced_flops = decode_flops = 0.0
+    decoded: dict[tuple, list[int]] = {}
+    for r in logs:
+        for j, t in enumerate(r.token_times):
+            in_window, in_trace = t0 < t <= t_end, a < t <= b
+            if not (in_window or in_trace):
+                continue
+            f = flops(j, r)
+            if in_window:
+                model_flops += f
+            if in_trace:
+                traced_flops += f
+                if j > 0:
+                    decode_flops += f
+                    decoded.setdefault((t, r.replica), []).append(
+                        r.prompt_len + j - 1)
+    return {"model_flops": model_flops, "traced_flops": traced_flops,
+            "traced_s": b - a, "decode_flops": decode_flops,
+            "decode_bytes": sum(ref.decode_bytes(doc, p)
+                                for p in decoded.values()),
+            "decode_steps": len(decoded)}
+
+
+def program_intervals(prog, t0: float, t_end: float) -> dict:
+    """The program recorder's intervals that started in ``[t0, t_end)``,
+    as durations by name."""
+    spans: dict[str, list[float]] = {}
+    for name, a, b in prog.intervals:
+        if t0 <= a < t_end:
+            spans.setdefault(name, []).append(b - a)
+    return spans
+
+
 class Session:
     """One cell's server with its weights, built once: calibration reuses it
     across seeds in one process."""
 
-    def __init__(self, cell, seed: int, devices, spans: Spans):
+    def __init__(self, cell, seed: int, devices, spans: Spans,
+                 program_spans=None):
         self.cell = cell
         self.spans = spans
+        self.program_spans = program_spans
+        self.window_counters: dict = {}
+        self.traced: tuple[float, float] | None = None
         self.cfg = model_config(cell.config)
         self.max_len = int(cell.traffic["max_len"])
         self.weights = cell.reference.make_weights(
             cell.config, jax_key(seed), devices[0])
         self.server = build_server(cell, self.cfg, self.weights,
-                                   self.max_len)
+                                   self.max_len, program_spans)
         gen = cell.generator.make(cell.traffic, seed, self.cfg.vocab)
         warm_up(self.server, gen.prompt_lens(), seed, self.cfg.vocab)
 
@@ -188,14 +252,17 @@ class Session:
               traffic: dict | None = None):
         """Offer the mix for ``seconds`` and drain.  Returns the logs and
         ``(t0, t_end, t_give_up)``: window start, end of its last step,
-        and when waiting stopped.  ``traffic`` replaces the cell's mix
-        parameters (the rate sweep varies the rate)."""
+        and when waiting stopped; ``self.traced`` is the traced
+        sub-window's span, if one was traced.  ``traffic`` replaces the
+        cell's mix parameters (the rate sweep varies the rate)."""
         from repro.core.deploy import ServeRequest
         cell, server, spans = self.cell, self.server, self.spans
         gen = cell.generator.make(traffic or cell.traffic, seed,
                                   self.cfg.vocab)
         logs: dict[str, timeline.RequestLog] = {}
         stamper = Stamper(server, logs)
+        prog = self.program_spans
+        before = dict(prog.counters) if prog is not None else {}
         t0 = time.perf_counter()
         deadline = t0 + seconds
         sub = devtrace.SubWindow(trace_dir, t0, seconds, trace_s)
@@ -235,6 +302,10 @@ class Session:
                 wait_for_arrival(deadline)
                 t_end = time.perf_counter()
         sub.close()
+        self.traced = sub.span
+        if prog is not None:
+            self.window_counters = {k: v - before.get(k, 0)
+                                    for k, v in prog.counters.items()}
         window = [r for r in logs.values() if r.in_window]
         give_up = t_end + give_up_s
         while any(not (r.done or r.rejected) for r in window) \
@@ -262,8 +333,12 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
     tests break the timed path with it)."""
     doc = cell.config
     spans = Spans(annotate=trace)
+    program_spans = None
+    if trace:
+        from repro.core.spans import Spans as ProgramSpans
+        program_spans = ProgramSpans(annotate=True)
     events = CompileEvents()
-    sess = Session(cell, seed, devices, spans)
+    sess = Session(cell, seed, devices, spans, program_spans)
     if tamper is not None:
         tamper(sess.server)
     t_window = time.perf_counter()
@@ -292,19 +367,20 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
               f"{1e3 * timeline.percentile(itl, q):.3f}"
               for q in (50, 90, 95, 99)) + f" of {len(itl)}",
           file=sys.stderr, flush=True)
-    model_flops = 0.0
-    for r in logs:
-        for j, t in enumerate(r.token_times):
-            if t0 < t <= t_end:
-                model_flops += (flops.prefill_flops(doc, r.prompt_len)
-                                if j == 0 else
-                                flops.decode_flops(doc, r.prompt_len + j - 1))
     steps = spans.durations("step", t0, t_end)
     peak = peaks(devices[0].device_kind) \
         if devices[0].platform == "tpu" else None
-    counters = {"model_flops": model_flops, "chips": len(devices),
-                "peak_flops_per_s": peak["bf16_flops_per_s"] if peak
-                else None}
+    # an untraced run, which reads no per-layer metric, counts the decode
+    # over the whole window
+    work = window_work(cell.reference, doc, logs, (t0, t_end),
+                       sess.traced or (t0, t_end))
+    counters = dict(work, chips=len(devices),
+                    peak_flops_per_s=peak["bf16_flops_per_s"] if peak
+                    else None,
+                    peak_hbm_bytes_per_s=peak["hbm_bytes_per_s"] if peak
+                    else None)
+    prog_spans = (program_intervals(program_spans, t0, t_end)
+                  if program_spans is not None else {})
     sess.close()
 
     sample = sample_for_check(logs, seed, int(doc["check"]["sample_tokens"]))
@@ -321,7 +397,9 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
         summary = devtrace.reduce(tr, sorted(tr.ops)[:len(devices)])
     rec = Record(attempted=len(window), failed=failed, end_to_end=e2e,
                  checks=checks, memory_peak_bytes=mem, window_s=window_s,
-                 spans={"step": steps}, counters=counters, trace=summary)
+                 spans={"step": steps}, counters=counters, trace=summary,
+                 program_spans=prog_spans,
+                 program_counters=sess.window_counters)
     return rec, t_window
 
 

@@ -1,7 +1,7 @@
 """The model's share of the chips' bf16 peak, in %: the operations that the
-prompt and output tokens processed in the window need (``chipbench.flops``,
-from the configuration's sizes), over the window's seconds, the number of
-chips and the peak of the device kind (``chipbench/peaks.json``)."""
+prompt and output tokens processed in the window need (the configuration's
+own ``prefill_flops`` and ``decode_flops``), over the window's seconds, the
+number of chips and the peak of the device kind (``chipbench/peaks.json``)."""
 
 
 def read(rec):
