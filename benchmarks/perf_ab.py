@@ -1359,9 +1359,9 @@ def run_cells():
     run("falcon_train_2_chunked_remat", "falcon-mamba-7b", "train_4k",
         f.scaled(remat="full"))
 
-    # ---- bonus E: deepseek-v3-671b decode_32k (weight-gather collectives) -
-    run("deepseek_decode_0_gather", "deepseek-v3-671b", "decode_32k",
-        d.scaled(moe_mode="dense"))
+    # ---- bonus E: deepseek-v3-671b decode_32k (chip-share layer vs EP) ----
+    run("deepseek_decode_0_local", "deepseek-v3-671b", "decode_32k",
+        d.scaled(moe_mode="local"))
     run("deepseek_decode_1_ep_a2a", "deepseek-v3-671b", "decode_32k", d)
 
 

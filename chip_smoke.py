@@ -127,15 +127,15 @@ def _checked_engine_cls():
                      "to requests only at one admission per prefill")
             return self._prefilling
 
-        def _sample(self, logits):
+        def _sample(self, logits, routed=None):
             self.n_logits += int(logits.size)
             self.n_nonfinite += int(jnp.sum(~jnp.isfinite(logits)))
             if self._prefilling:                 # the admitted prompt's
                 self._keep(self._prefilling.pop().uid, logits[0])
-            return super()._sample(logits)
+            return super()._sample(logits, routed)
 
         def _decode_complete(self, pending):
-            for _, active, logits in pending:
+            for _, active, logits, _ in pending:
                 for i, lane in active:
                     self._keep(lane.req.uid, logits[i])
             super()._decode_complete(pending)

@@ -20,6 +20,7 @@ ARCHS = (
     "qwen3-0.6b",
     "falcon-mamba-7b",
     "hubert-xlarge",
+    "deepseek-v2-lite",
 )
 
 _MOD = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

@@ -42,4 +42,4 @@ def smoke():
                          n_shared_experts=1, top_k=2, vocab=512,
                          q_lora_rank=48, kv_lora_rank=32, qk_rope_dim=8,
                          qk_nope_dim=16, v_head_dim=16, dtype="float32",
-                         moe_mode="dense", expert_shards=1, remat="none")
+                         moe_mode="local", expert_shards=1, remat="none")

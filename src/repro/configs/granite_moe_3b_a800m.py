@@ -32,4 +32,4 @@ def smoke():
     return CONFIG.scaled(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                          head_dim=16, d_ff=96, moe_d_ff=96, n_experts=8,
                          top_k=2, vocab=512, dtype="float32",
-                         moe_mode="dense", expert_shards=1)
+                         moe_mode="local", expert_shards=1)

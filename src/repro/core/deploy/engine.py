@@ -200,10 +200,13 @@ def _jitted(cfg):
     cache index**: all in-flight sequences advance in ONE fixed-shape
     dispatch regardless of their (different) positions — the core
     continuous-batching capability the lockstep path lacks.  The lane cache
-    is donated and each lane's new K/V rows are written into it in place."""
+    is donated and each lane's new K/V rows are written into it in place.
+    Decode returns ``(logits, caches, routed)``: ``routed`` counts, per MoE
+    layer and lane, the pairs routed to experts held here ((MoE layers,
+    lanes) int32; None without MoE)."""
     import jax
 
-    from ...models.transformer import decode_step, prefill
+    from ...models.transformer import Dist, _forward, _head, prefill
 
     # named functions: a profiler trace shows the programs as
     # ``jit_serve_prefill`` and ``jit_serve_decode``
@@ -211,7 +214,9 @@ def _jitted(cfg):
         return prefill(p, b, cfg)
 
     def serve_decode(p, tb, c, i):
-        return decode_step(p, tb, c, i, cfg)
+        h, caches, routed = _forward(p, cfg, tb, Dist(), decoding=True,
+                                     caches=c, index=i)
+        return _head(p, h[:, -1]), caches, routed
 
     return jax.jit(serve_prefill), jax.jit(serve_decode, donate_argnums=(2,))
 
@@ -254,7 +259,11 @@ class ServeEngine:
     ``spans`` (a :class:`~repro.core.spans.Spans`) records the tick from
     inside: ``tick``, ``admit`` with its ``prefill`` and ``splice``,
     ``dispatch``, ``fetch`` (every wait for sampled ids), the ``decode`` and
-    ``queue`` intervals and the ``prefill_tokens`` count.  Off by default."""
+    ``queue`` intervals and the ``prefill_tokens`` count; for an MoE model,
+    per decode execution, ``moe_pairs`` (busy lanes x top_k x MoE layers),
+    ``moe_pairs_here`` (those routed to experts held here, fetched with the
+    sampled ids) and ``moe_expert_slots`` (held experts x MoE layers).  Off
+    by default: the routed counts are then never fetched."""
 
     def __init__(self, cfg, params=None, *, max_len: int = 128,
                  max_slots: int = 4, prefill_chunk: int = 2,
@@ -461,7 +470,9 @@ class ServeEngine:
                         batch.caches = _write_lane(batch.caches, li, one)
 
     # -- decode --------------------------------------------------------------
-    def _sample(self, logits):
+    def _sample(self, logits, routed=None):
+        """Sampled ids on the host; with ``routed`` (a device array) the pair
+        ``(ids, routed)``, fetched together."""
         import jax
         import jax.numpy as jnp
         if self.temperature > 0:
@@ -470,7 +481,10 @@ class ServeEngine:
         else:
             ids = jnp.argmax(logits, -1)
         with self.spans.span("fetch"):
-            return np.asarray(ids).astype(np.int32)
+            if routed is None:
+                return np.asarray(ids).astype(np.int32)
+            ids, routed = jax.device_get((ids, routed))
+            return np.asarray(ids).astype(np.int32), routed
 
     def _decode_dispatch(self) -> list[tuple]:
         """Phase 1 of a decode tick: launch ONE decode dispatch per
@@ -503,17 +517,22 @@ class ServeEngine:
             tb = {"tokens": toks, "positions": pos}
             if cfg.mrope:
                 tb["positions3"] = np.broadcast_to(pos[..., None], (N, 1, 3))
-            logits, batch.caches = dec_fn(self.params, tb, batch.caches, idx)
+            logits, batch.caches, routed = dec_fn(self.params, tb,
+                                                  batch.caches, idx)
             self.n_decode_batches += 1
-            pending.append((variant, active, logits))
+            pending.append((variant, active, logits, routed))
         return pending
 
     def _decode_complete(self, pending: list[tuple]) -> None:
         """Phase 2 of a decode tick: sample next tokens (this is where the
         host blocks on device results) and advance lane bookkeeping."""
-        for variant, active, logits in pending:
+        for variant, active, logits, routed in pending:
             batch = self.batches[variant]
-            nxt = self._sample(logits)
+            if routed is not None and self.spans is not OFF:
+                nxt, routed = self._sample(logits, routed)
+                self._count_routed(self.cfgs[variant], active, routed)
+            else:
+                nxt = self._sample(logits)
             t_now = _time.perf_counter()
             self.spans.record("decode", self._t_dispatch, t_now)
             for i, lane in active:
@@ -523,6 +542,15 @@ class ServeEngine:
                 lane.last = tok
                 if self._maybe_finish(lane, t_now):
                     batch.lanes[i] = None
+
+    def _count_routed(self, cfg, active, routed) -> None:
+        """The routed counters of one decode execution over its busy lanes
+        ``active`` (``routed``: the (MoE layers, lanes) pairs routed to held
+        experts; idle lanes are computed but not counted)."""
+        n_moe, busy = len(routed), [i for i, _ in active]
+        self.spans.count("moe_pairs", len(busy) * cfg.top_k * n_moe)
+        self.spans.count("moe_pairs_here", int(np.sum(routed[:, busy])))
+        self.spans.count("moe_expert_slots", cfg.n_experts_here * n_moe)
 
     def _decode_tick(self) -> None:
         self._decode_complete(self._decode_dispatch())

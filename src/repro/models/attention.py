@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .common import ModelConfig
-from .layers import apply_mrope, apply_rope, dense_init, rms_norm
+from .layers import (apply_mrope, apply_rope, dense_init, rms_norm,
+                     yarn_freqs, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -38,10 +39,16 @@ def init_attn(key, cfg: ModelConfig, dtype) -> dict:
     if cfg.mla:
         r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
         nope, rope, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        if r_q:
+            q = {"wq_a": dense_init(ks[0], (d, r_q), dtype=dtype),
+                 "q_norm": jnp.ones((r_q,), dtype),
+                 "wq_b": dense_init(ks[1], (r_q, H, nope + rope),
+                                    dtype=dtype)}
+        else:
+            q = {"wq": dense_init(ks[0], (d, H, nope + rope), in_axis=0,
+                                  dtype=dtype)}
         return {
-            "wq_a": dense_init(ks[0], (d, r_q), dtype=dtype),
-            "q_norm": jnp.ones((r_q,), dtype),
-            "wq_b": dense_init(ks[1], (r_q, H, nope + rope), dtype=dtype),
+            **q,
             "wkv_a": dense_init(ks[2], (d, r_kv + rope), dtype=dtype),
             "kv_norm": jnp.ones((r_kv,), dtype),
             "wkv_b": dense_init(ks[3], (r_kv, H, nope + vdim), dtype=dtype),
@@ -234,44 +241,89 @@ def gqa_decode(p, cfg: ModelConfig, x, cache_k, cache_v, layer, index,
 
 
 # --------------------------------------------------------------------------
-# MLA (DeepSeek-V3)
+# MLA (DeepSeek-V2/V3)
 # --------------------------------------------------------------------------
+#
+# Rotary layout: the published model de-interleaves the rope columns of q and
+# k before ``rotate_half``.  Here those columns of ``wq``/``wq_b`` and
+# ``wkv_a`` are taken as already permuted, so ``apply_rope`` applies
+# ``rotate_half`` to them directly: the same model, its weights' rope
+# columns stored in the permuted order.
 
-def mla_forward(p, cfg: ModelConfig, x, positions, dist=None):
-    """Full-sequence MLA. Returns (y, (c_kv, k_rope)) — the compressed cache."""
-    nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
-    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
 
+def _mla_rope(cfg: ModelConfig, x, positions):
+    """Rotary embedding of MLA's rope part (..., S, heads, rope); YaRN's
+    frequencies and cos/sin factor where ``cfg.yarn_factor`` is set."""
+    if not cfg.yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    f = cfg.yarn_factor
+    freqs = yarn_freqs(cfg.qk_rope_dim, cfg.rope_theta, f,
+                       cfg.yarn_original_max_pos)
+    scale = yarn_mscale(f, cfg.yarn_mscale) / yarn_mscale(
+        f, cfg.yarn_mscale_all_dim)
+    return apply_rope(x, positions, cfg.rope_theta, freqs, scale)
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(nope + rope) ** -0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim) ** 2`` where YaRN is on."""
+    scale = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_query(p, cfg: ModelConfig, x, positions):
+    """(q_nope, q_rope) of every head: through the q-LoRA (``wq_a``,
+    ``q_norm``, ``wq_b``) or, with ``q_lora_rank`` 0, straight from
+    ``wq``."""
+    nope = cfg.qk_nope_dim
+    if cfg.q_lora_rank:
+        q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    return q[..., :nope], _mla_rope(cfg, q[..., nope:], positions)
+
+
+def _mla_latent(p, cfg: ModelConfig, x, positions):
+    """The cached latent: normalised ``c_kv`` (B, S, r_kv) and the rotated
+    shared ``k_rope`` (B, S, rope)."""
     kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
     c_kv, k_rope = kv[..., :cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
     c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta)
+    return c_kv, _mla_rope(cfg, k_rope[..., None, :], positions)[..., 0, :]
 
-    kvu = jnp.einsum("bsr,rhk->bshk", c_kv, p["wkv_b"])
-    k_nope, v = kvu[..., :nope], kvu[..., nope:]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + (rope,))], -1)
-    qk = jnp.concatenate([q_nope, q_rope], -1)
-    if dist is not None and getattr(dist, "active", False):
-        dp, mdl = dist.batch_axes, dist.model_axis
-        qk = _shard(qk, dist, dp, None, mdl, None)
-        k = _shard(k, dist, dp, None, mdl, None)
-        v = _shard(v, dist, dp, None, mdl, None)
 
-    S = x.shape[1]
-    scale = 1.0 / np.sqrt(nope + rope)
-    if cfg.attn_impl == "blockwise":
-        out = blockwise_sdpa(qk, k, v, causal=True, scale=scale,
-                             block_q=cfg.attn_block, block_k=cfg.attn_block)
-    else:
-        mask = causal_mask(S, S)
-        out = _sdpa(qk, k, v, mask, scale)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, (c_kv, k_rope[..., 0, :])
+def mla_forward(p, cfg: ModelConfig, x, positions, dist=None):
+    """Full-sequence MLA. Returns (y, (c_kv, k_rope)) — the compressed cache."""
+    with jax.named_scope("mla"):
+        nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
+        q_nope, q_rope = _mla_query(p, cfg, x, positions)
+        c_kv, k_rope = _mla_latent(p, cfg, x, positions)
+        kvu = jnp.einsum("bsr,rhk->bshk", c_kv, p["wkv_b"])
+        k_nope, v = kvu[..., :nope], kvu[..., nope:]
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[..., None, :],
+                                      k_nope.shape[:-1] + (rope,))], -1)
+        qk = jnp.concatenate([q_nope, q_rope], -1)
+        if dist is not None and getattr(dist, "active", False):
+            dp, mdl = dist.batch_axes, dist.model_axis
+            qk = _shard(qk, dist, dp, None, mdl, None)
+            k = _shard(k, dist, dp, None, mdl, None)
+            v = _shard(v, dist, dp, None, mdl, None)
+
+        S = x.shape[1]
+        scale = mla_scale(cfg)
+        if cfg.attn_impl == "blockwise":
+            out = blockwise_sdpa(qk, k, v, causal=True, scale=scale,
+                                 block_q=cfg.attn_block,
+                                 block_k=cfg.attn_block)
+        else:
+            out = _sdpa(qk, k, v, causal_mask(S, S), scale)
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return y, (c_kv, k_rope)
 
 
 def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, layer,
@@ -280,31 +332,24 @@ def mla_decode(p, cfg: ModelConfig, x, cache_ckv, cache_krope, layer,
     kv_lora space, so the cache is (B, S, r_kv) + (B, S, rope) only, here
     layer ``layer`` of stacks with a leading layer dim (``index`` as in
     :func:`gqa_decode`)."""
-    nope, rope, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
-    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    # absorb k_nope projection into the query:  q' = q_nope @ W_kv_b[:, :, :nope]^T
-    q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["wkv_b"][..., :nope])
-
-    kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
-    c_kv, k_rope = kv[..., :cfg.kv_lora_rank], kv[..., cfg.kv_lora_rank:]
-    c_kv = rms_norm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[..., None, :], positions,
-                        cfg.rope_theta)[..., 0, :]
-    cache_ckv = _write_token(cache_ckv, layer, index, c_kv)
-    cache_krope = _write_token(cache_krope, layer, index, k_rope)
-    ckv = cache_ckv[layer]
-    logits = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)
-              + jnp.einsum("bshk,btk->bhst", q_rope, cache_krope[layer]))
-    logits = logits.astype(jnp.float32) / np.sqrt(nope + rope)
-    mask = _decode_mask(index, ckv.shape[1])
-    logits = jnp.where(mask[:, None], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, -1).astype(x.dtype)
-    ctx = jnp.einsum("bhst,btr->bshr", probs, ckv)
-    # un-absorb the value projection
-    out = jnp.einsum("bshr,rhk->bshk", ctx, p["wkv_b"][..., nope:])
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    with jax.named_scope("mla"):
+        nope = cfg.qk_nope_dim
+        q_nope, q_rope = _mla_query(p, cfg, x, positions)
+        # absorb the k_nope projection into the query:
+        # q' = q_nope @ W_kv_b[:, :, :nope]^T
+        q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["wkv_b"][..., :nope])
+        c_kv, k_rope = _mla_latent(p, cfg, x, positions)
+        cache_ckv = _write_token(cache_ckv, layer, index, c_kv)
+        cache_krope = _write_token(cache_krope, layer, index, k_rope)
+        ckv = cache_ckv[layer]
+        logits = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)
+                  + jnp.einsum("bshk,btk->bhst", q_rope, cache_krope[layer]))
+        logits = logits.astype(jnp.float32) * mla_scale(cfg)
+        mask = _decode_mask(index, ckv.shape[1])
+        logits = jnp.where(mask[:, None], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, -1).astype(x.dtype)
+        ctx = jnp.einsum("bhst,btr->bshr", probs, ckv)
+        # un-absorb the value projection
+        out = jnp.einsum("bshr,rhk->bshk", ctx, p["wkv_b"][..., nope:])
+        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, (cache_ckv, cache_krope)

@@ -22,10 +22,16 @@ class ModelConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
+    norm_topk_prob: bool = True        # renormalise the top-k gates to sum 1
+    first_k_dense_replace: int = 0     # leading layers with a dense SwiGLU
+    # this chip's share of each expert layer: experts ``expert_block *
+    # experts_held`` on (0 -> all ``n_experts``, the router's width)
+    experts_held: int = 0
+    expert_block: int = 0
 
     # MLA (DeepSeek)
     mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0       # 0 -> queries straight from ``wq``
     kv_lora_rank: int = 0
     qk_rope_dim: int = 64
     qk_nope_dim: int = 128
@@ -48,12 +54,18 @@ class ModelConfig:
     causal: bool = True        # False -> encoder-only (hubert)
     embedding_inputs: bool = False  # modality stub: inputs are embeddings
     rope_theta: float = 1e4
+    # YaRN rotary scaling (DeepSeek-V2); ``yarn_factor`` 0 -> plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
 
     # distribution / perf knobs (overridable per run / by GEVO-Shard)
     remat: str = "none"        # none | full  — activation checkpoint per layer
-    moe_mode: str = "dense"    # dense | ep_a2a  (decode always uses gather)
+    moe_mode: str = "local"    # local | ep_a2a; ep_a2a needs a mesh, else
+    #                            the chip-share layer (moe_share) serves
     expert_shards: int = 1     # pad expert dim so it divides this (EP width)
     attn_impl: str = "naive"   # naive | blockwise (flash-style, O(S) memory)
     attn_block: int = 512      # q/kv block for blockwise attention
@@ -81,15 +93,28 @@ class ModelConfig:
     def scaled(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
 
+    @property
+    def n_experts_here(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_k_dense_replace \
+            if self.n_experts else 0
+
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + layers), for 6ND math."""
+        """Approximate parameter count (embeddings + layers, no norms), for
+        6ND math: the leading dense layers apart, and of the routed experts
+        those held here."""
         d, v = self.d_model, self.vocab
         emb = v * d * 2  # in + out embedding (untied)
-        per_layer = 0
+        per_layer, n_dense, dense = 0, 0, 0
         if self.family in ("dense", "moe", "vlm", "encoder", "mla_moe"):
             if self.mla:
-                q = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * (
-                    self.qk_nope_dim + self.qk_rope_dim)
+                qk = self.n_heads * (self.qk_nope_dim + self.qk_rope_dim)
+                r = self.q_lora_rank
+                q = d * r + r * qk if r else d * qk
                 kv = d * (self.kv_lora_rank + self.qk_rope_dim) + \
                     self.kv_lora_rank * self.n_heads * (
                         self.qk_nope_dim + self.v_head_dim)
@@ -98,19 +123,21 @@ class ModelConfig:
             else:
                 attn = d * self.hd * (self.n_heads + 2 * self.n_kv_heads) \
                     + self.n_heads * self.hd * d
+            dense = attn + 3 * d * self.d_ff
             if self.n_experts:
-                ff = 3 * d * self.moe_d_ff * (self.n_experts
-                                              + self.n_shared_experts) \
+                moe = attn + 3 * d * self.moe_d_ff * (
+                    self.n_experts_here + self.n_shared_experts) \
                     + d * self.n_experts
+                n_dense, per_layer = self.first_k_dense_replace, moe
             else:
-                ff = 3 * d * self.d_ff
-            per_layer = attn + ff
+                per_layer = dense
         elif self.family in ("ssm", "hybrid"):
             di, n = self.d_inner, self.ssm_state
             # in_proj (x,z), conv, dt/B/C projections, out_proj
             per_layer = d * di * 2 + di * self.ssm_conv + di * (2 * n + 2) \
                 + di * d
-        n_param = emb + self.n_layers * per_layer
+        n_param = emb + n_dense * dense \
+            + (self.n_layers - n_dense) * per_layer
         if self.family == "hybrid" and self.attn_every:
             # ONE weight-shared attention + MLP block
             shared = 4 * d * self.n_heads * self.hd + 3 * d * self.d_ff
@@ -121,8 +148,6 @@ class ModelConfig:
         """Params touched per token (MoE: top_k + shared experts only)."""
         if not self.n_experts:
             return self.param_count()
-        d = self.d_model
-        full = self.param_count()
-        all_expert = 3 * d * self.moe_d_ff * self.n_experts * self.n_layers
-        active_expert = 3 * d * self.moe_d_ff * self.top_k * self.n_layers
-        return int(full - all_expert + active_expert)
+        expert = 3 * self.d_model * self.moe_d_ff * self.n_moe_layers
+        return int(self.param_count()
+                   - expert * (self.n_experts_here - self.top_k))

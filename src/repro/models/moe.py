@@ -1,10 +1,16 @@
 """Mixture-of-Experts blocks.
 
-Two execution modes:
+Three execution paths:
 
-* ``dense``  — reference implementation: every expert computes every token,
-  combined with the top-k gate mask.  O(E) compute — used at smoke scale and
-  as the numerical oracle for the EP path.
+* :func:`moe_share` — the layer without a mesh, for prefill and decode alike:
+  routes every token over all ``n_experts``, keeps the (token, expert) pairs
+  whose expert this chip holds (``experts_held`` experts from ``expert_block
+  * experts_held``; all of them by default) and computes them with a
+  dropless grouped product (``lax.ragged_dot``) over pairs sorted by expert.
+  With every expert held it computes what :func:`moe_dense` computes.
+* :func:`moe_dense` — reference implementation: every expert computes every
+  token, combined with the top-k gate mask.  O(E) compute — the numerical
+  oracle for the other two.
 * ``ep_a2a`` — TPU expert parallelism: experts sharded over the ``model``
   mesh axis, tokens dispatched with capacity-C buffers through a pair of
   ``all_to_all`` collectives inside ``shard_map`` (DeepSeek-style EP).  This
@@ -36,7 +42,8 @@ def expert_pad(cfg: ModelConfig, n_shards: int = 1) -> int:
 
 def init_moe(key, cfg: ModelConfig, dtype, n_expert_shards: int = 1) -> dict:
     d, ff = cfg.d_model, cfg.moe_d_ff
-    ep = expert_pad(cfg, n_expert_shards)
+    ep = (cfg.experts_held if cfg.experts_held
+          else expert_pad(cfg, n_expert_shards))
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, cfg.n_experts), dtype=jnp.float32),
@@ -53,12 +60,15 @@ def init_moe(key, cfg: ModelConfig, dtype, n_expert_shards: int = 1) -> dict:
     return p
 
 
-def _route(x2, router, n_experts, top_k):
-    """x2: (n, d) -> (weights (n,k), indices (n,k)) with normalized gates."""
+def _route(x2, router, cfg: ModelConfig):
+    """x2: (n, d) -> (weights (n,k), indices (n,k)): softmax over every
+    expert, greedy top-k, the k gates renormalised to sum 1 only with
+    ``norm_topk_prob``."""
     logits = jnp.einsum("nd,de->ne", x2.astype(jnp.float32), router)
     gates = jax.nn.softmax(logits, -1)
-    w, idx = jax.lax.top_k(gates, top_k)
-    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-9)
+    w, idx = jax.lax.top_k(gates, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-9)
     return w.astype(x2.dtype), idx
 
 
@@ -75,9 +85,8 @@ def _shared(p, x):
 def moe_dense(p, cfg: ModelConfig, x):
     """x: (B, S, d).  Computes all experts (reference / smoke scale)."""
     B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
     x2 = x.reshape(-1, d)
-    w, idx = _route(x2, p["router"], E, k)
+    w, idx = _route(x2, p["router"], cfg)
     onehot = jax.nn.one_hot(idx, p["w_gate"].shape[0], dtype=x.dtype)
     combine = jnp.einsum("nk,nke->ne", w, onehot)                # (n, E_pad)
     g = jax.nn.silu(jnp.einsum("nd,edf->enf", x2, p["w_gate"]))
@@ -110,23 +119,39 @@ def moe_ep_a2a_decode(p, cfg: ModelConfig, x, *, expert_axis: str = "model",
     return jax.lax.psum(y, expert_axis)
 
 
-def moe_gather(p, cfg: ModelConfig, x):
-    """Decode-path MoE: gather the k selected experts' weights per token.
+def moe_share(p, cfg: ModelConfig, x):
+    """The chip's share of an expert layer.  x: (B, S, d).
 
-    For small token counts (one decode step) this moves k*d*ff weight bytes
-    per token instead of dispatching tokens — the right trade at batch sizes
-    far below the expert count.  x: (B, S, d) with tiny B*S."""
+    Every token is routed over all ``n_experts``; of its ``top_k`` pairs,
+    those whose expert is held here (``p["w_gate"]`` holds experts
+    ``expert_block * G`` to ``expert_block * G + G - 1``, ``G`` its length)
+    are sorted by expert and computed by one dropless grouped product per
+    projection: every such pair is computed, whatever the load.  The results
+    return to their tokens weighted by the gate, in the order of the top-k
+    (so a token's output does not depend on the rest of the batch), and the
+    shared experts are added once.  Returns ``(y, n_here)``: the output and,
+    per row of ``x``, the number of its pairs routed to held experts ((B,)
+    int32)."""
     B, S, d = x.shape
     x2 = x.reshape(-1, d)
-    w, idx = _route(x2, p["router"], cfg.n_experts, cfg.top_k)
-    wg = jnp.take(p["w_gate"], idx, axis=0)                  # (n, k, d, ff)
-    wu = jnp.take(p["w_up"], idx, axis=0)
-    wd = jnp.take(p["w_down"], idx, axis=0)
-    g = jax.nn.silu(jnp.einsum("nd,nkdf->nkf", x2, wg))
-    u = jnp.einsum("nd,nkdf->nkf", x2, wu)
-    y = jnp.einsum("nkf,nkfd->nd", (g * u) * w[..., None], wd)
-    y = y + _shared(p, x2)
-    return y.reshape(B, S, d)
+    n, k = x2.shape[0], cfg.top_k
+    G = p["w_gate"].shape[0]
+    w, idx = _route(x2, p["router"], cfg)
+    local = idx.reshape(-1) - cfg.expert_block * G              # (n*k,)
+    here = (local >= 0) & (local < G)
+    group = jnp.where(here, local, G)            # pairs not held sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(group, G, dtype=jnp.int32), axis=0)
+    xs = x2[order // k]                                         # (n*k, d)
+    g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], sizes))
+    u = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+    ys = jax.lax.ragged_dot(g * u, p["w_down"], sizes)          # rows past
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    ys = ys[back].reshape(n, k, d)                              # sizes: 0
+    gate = jnp.where(here.reshape(n, k), w, 0).astype(ys.dtype)
+    y = jnp.einsum("nk,nkd->nd", gate, ys) + _shared(p, x2)
+    return y.reshape(B, S, d), jnp.sum(here.reshape(B, S * k), -1,
+                                       dtype=jnp.int32)
 
 
 # --------------------------------------------------------------------------
@@ -176,7 +201,7 @@ def moe_ep_a2a(p, cfg: ModelConfig, x, *, expert_axis: str = "model",
     k = cfg.top_k
     cap = int(np.ceil(n * k / e_pad * capacity_factor / 8.0) * 8)
 
-    w, idx = _route(x, p["router"], cfg.n_experts, k)
+    w, idx = _route(x, p["router"], cfg)
     buf, meta = _dispatch_local(x, w, idx, e_pad, cap, valid)   # (E_pad, C, d)
     # send expert-slices to their owners; receive my experts' tokens from all
     # peers.  tiled a2a: rows [j*e_loc:(j+1)*e_loc] -> peer j; received chunks

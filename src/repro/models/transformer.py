@@ -5,15 +5,18 @@ Layers are stacked (leading L dim) and driven by ``lax.scan`` so the lowered
 HLO stays compact for 61-80-layer models.  Families:
 
   dense / vlm / encoder : attention + SwiGLU MLP
-  moe / mla_moe         : attention (GQA or MLA) + MoE FFN
+  moe / mla_moe         : attention (GQA or MLA) + MoE FFN, after
+                          ``first_k_dense_replace`` leading layers whose FFN
+                          is a dense SwiGLU (``params["dense_layers"]``)
   ssm                   : mamba1 blocks (attention-free)
   hybrid                : mamba2 backbone + ONE weight-shared attention+MLP
                           block applied every ``attn_every`` layers (zamba2)
 
 Distribution is carried by ``Dist`` (mesh + axis names); everything else is
 global-semantics einsum, partitioned by GSPMD according to the shardings in
-``launch/shardings.py``.  The MoE FFN switches between the dense reference,
-shard_map expert-parallel a2a, and decode-time weight gathering.
+``launch/shardings.py``.  The MoE FFN is shard_map expert-parallel a2a on a
+mesh with ``moe_mode="ep_a2a"``, and otherwise the chip-share layer
+(``moe.moe_share``), which serves prefill and decode alike.
 """
 
 from __future__ import annotations
@@ -34,8 +37,7 @@ from .common import ModelConfig
 from .layers import dense_init, rms_norm, softmax_cross_entropy, swiglu
 from .mamba import (init_mamba, mamba1_decode, mamba1_seq, mamba2_decode,
                     mamba2_seq)
-from .moe import (init_moe, moe_dense, moe_ep_a2a, moe_ep_a2a_decode,
-                  moe_gather)
+from .moe import init_moe, moe_ep_a2a, moe_ep_a2a_decode, moe_share
 
 
 @dataclass(frozen=True)
@@ -66,7 +68,7 @@ def _init_mlp(key, cfg, dtype):
             "down": dense_init(k3, (ff, d), dtype=dtype)}
 
 
-def _init_layer(key, cfg: ModelConfig, dtype):
+def _init_layer(key, cfg: ModelConfig, dtype, dense: bool = False):
     ks = jax.random.split(key, 4)
     if cfg.family == "ssm":
         return {"ln": jnp.ones((cfg.d_model,), dtype),
@@ -77,7 +79,7 @@ def _init_layer(key, cfg: ModelConfig, dtype):
     p = {"ln1": jnp.ones((cfg.d_model,), dtype),
          "ln2": jnp.ones((cfg.d_model,), dtype),
          "attn": init_attn(ks[0], cfg, dtype)}
-    if cfg.n_experts:
+    if cfg.n_experts and not dense:
         p["moe"] = init_moe(ks[1], cfg, dtype,
                             n_expert_shards=cfg.expert_shards)
     else:
@@ -89,14 +91,19 @@ def init_params(cfg: ModelConfig, key=None, dtype=None) -> dict:
     key = jax.random.PRNGKey(0) if key is None else key
     dtype = dtype or _dtype(cfg)
     k_emb, k_lay, k_out, k_sh = jax.random.split(key, 4)
+    n_dense = cfg.first_k_dense_replace
+    keys = jax.random.split(k_lay, cfg.n_layers)
     params = {
         "embed": dense_init(k_emb, (cfg.vocab, cfg.d_model), in_axis=-1,
                             dtype=dtype),
         "ln_f": jnp.ones((cfg.d_model,), dtype),
         "out": dense_init(k_out, (cfg.d_model, cfg.vocab), dtype=dtype),
         "layers": jax.vmap(lambda k: _init_layer(k, cfg, dtype))(
-            jax.random.split(k_lay, cfg.n_layers)),
+            keys[n_dense:]),
     }
+    if n_dense:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_layer(k, cfg, dtype, dense=True))(keys[:n_dense])
     if cfg.family == "hybrid":  # one weight-shared attention + MLP block
         ka, km = jax.random.split(k_sh)
         params["shared"] = {
@@ -113,6 +120,10 @@ def init_params(cfg: ModelConfig, key=None, dtype=None) -> dict:
 # --------------------------------------------------------------------------
 
 def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
+    """The MoE FFN and, per row of ``x``, the number of (token, expert)
+    pairs it routed to experts held here ((B,) int32; on the mesh every
+    expert is held)."""
+    n_pairs = jnp.full(x.shape[:1], x.shape[1] * cfg.top_k, jnp.int32)
     if decoding:
         if cfg.moe_mode == "ep_a2a" and dist.active:
             # EP decode: tokens striped over the expert axis, a2a dispatch;
@@ -133,8 +144,7 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
                 local_dec, mesh=dist.mesh,
                 in_specs=(P(dist.batch_axes, None, None), pspec),
                 out_specs=P(dist.batch_axes, None, None), check_vma=False)
-            return fn(x, p)
-        return moe_gather(p, cfg, x)
+            return fn(x, p), n_pairs
     if cfg.moe_mode == "ep_a2a" and dist.active:
         pspec = {"router": P(), "w_gate": P(dist.model_axis),
                  "w_up": P(dist.model_axis), "w_down": P(dist.model_axis)}
@@ -151,8 +161,9 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
             in_specs=(P(dist.batch_axes, dist.model_axis, None), pspec),
             out_specs=P(dist.batch_axes, dist.model_axis, None),
             check_vma=False)
-        return fn(x, p)
-    return moe_dense(p, cfg, x)
+        return fn(x, p), n_pairs
+    with jax.named_scope("moe"):
+        return moe_share(p, cfg, x)
 
 
 # --------------------------------------------------------------------------
@@ -161,6 +172,9 @@ def _moe_apply(p, cfg: ModelConfig, x, dist: Dist, decoding: bool):
 
 def _attn_layer_fwd(lp, cfg, x, positions, dist, decoding=False,
                     cache=None, index=None, layer=None):
+    """One attention-family layer.  Returns ``(x, new_cache, n_here)``:
+    ``n_here`` counts the pairs its MoE FFN routed to experts held here
+    (None for a dense FFN)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.mla:
         if decoding:
@@ -178,11 +192,12 @@ def _attn_layer_fwd(lp, cfg, x, positions, dist, decoding=False,
                                        dist=dist)
     x = x + a
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        f = _moe_apply(lp["moe"], cfg, h, dist, decoding)
+    n_here = None
+    if "moe" in lp:
+        f, n_here = _moe_apply(lp["moe"], cfg, h, dist, decoding)
     else:
         f = swiglu(h, lp["mlp"]["gate"], lp["mlp"]["up"], lp["mlp"]["down"])
-    return x + f, new_cache
+    return x + f, new_cache, n_here
 
 
 def _mamba_layer_fwd(lp, cfg, x, decoding=False, cache=None):
@@ -252,30 +267,46 @@ def _embed(params, cfg: ModelConfig, batch: dict):
 
 def _stack_attn(params, cfg, x, positions, dist, decoding=False,
                 caches=None, index=None):
-    """scan over stacked attention-family layers.  Decoding carries the
-    whole stacked caches through the scan, and each layer writes its new
-    K/V rows into them in place: the caches are never rebuilt as scan
-    outputs, so a donated cache is updated without a copy."""
+    """scan over stacked attention-family layers: the leading dense layers
+    (``params["dense_layers"]``), if any, then ``params["layers"]``.  The
+    caches stay one stack over every layer, in layer order.  Decoding
+    carries the whole stacked caches through the scans, and each layer
+    writes its new K/V rows into them in place: the caches are never
+    rebuilt as scan outputs, so a donated cache is updated without a copy.
+    Returns ``(x, caches, n_here)``, ``n_here`` the (MoE layers, B) counts
+    of each row's pairs routed to held experts, or None without MoE
+    layers."""
     names = ("ckv", "krope") if cfg.mla else ("k", "v")
+    stacks = [(params["dense_layers"], 0)] if "dense_layers" in params \
+        else []
+    stacks.append((params["layers"], cfg.first_k_dense_replace))
     if decoding:
         def dec_body(carry, xs):
             h, c0, c1 = carry
             lp, layer = xs
-            h, (c0, c1) = _attn_layer_fwd(lp, cfg, h, positions, dist, True,
-                                          (c0, c1), index, layer)
-            return (h, c0, c1), None
+            h, (c0, c1), n_here = _attn_layer_fwd(
+                lp, cfg, h, positions, dist, True, (c0, c1), index, layer)
+            return (h, c0, c1), n_here
 
-        (x, c0, c1), _ = lax.scan(
-            dec_body, (x, caches[names[0]], caches[names[1]]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
-        return x, {names[0]: c0, names[1]: c1}
+        c0, c1 = caches[names[0]], caches[names[1]]
+        for layers, first in stacks:
+            n = jax.tree.leaves(layers)[0].shape[0]
+            (x, c0, c1), n_here = lax.scan(
+                dec_body, (x, c0, c1), (layers, first + jnp.arange(n)))
+        return x, {names[0]: c0, names[1]: c1}, n_here
 
     def body(carry, lp):
-        return _attn_layer_fwd(lp, cfg, carry, positions, dist)
+        h, c, n_here = _attn_layer_fwd(lp, cfg, carry, positions, dist)
+        return h, (c, n_here)
 
     fn = jax.checkpoint(body) if cfg.remat == "full" else body
-    x, (nc0, nc1) = lax.scan(fn, x, params["layers"])
-    return x, {names[0]: nc0, names[1]: nc1}
+    out = []
+    for layers, _ in stacks:
+        x, (c, n_here) = lax.scan(fn, x, layers)
+        out.append(c)
+    nc0, nc1 = (jnp.concatenate([c[i] for c in out]) if len(out) > 1
+                else out[0][i] for i in (0, 1))
+    return x, {names[0]: nc0, names[1]: nc1}, n_here
 
 
 def _stack_ssm(params, cfg, x, dist, decoding=False, caches=None):
@@ -388,19 +419,22 @@ def _stack_hybrid(params, cfg, x, positions, dist, decoding=False,
 
 def _forward(params, cfg: ModelConfig, batch: dict, dist: Dist,
              decoding=False, caches=None, index=None):
-    """Returns (final hidden states (B, S, d), new caches)."""
+    """Returns (final hidden states (B, S, d), new caches, the (MoE
+    layers, B) counts of each row's pairs routed to held experts or
+    None)."""
     x, positions = _embed(params, cfg, batch)
     if decoding:  # one cache length per row: a scalar serves every row
         index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), x.shape[:1])
+    n_here = None
     if cfg.family == "ssm":
         x, new_caches = _stack_ssm(params, cfg, x, dist, decoding, caches)
     elif cfg.family == "hybrid":
         x, new_caches = _stack_hybrid(params, cfg, x, positions, dist,
                                       decoding, caches, index)
     else:
-        x, new_caches = _stack_attn(params, cfg, x, positions, dist,
-                                    decoding, caches, index)
-    return rms_norm(x, params["ln_f"], cfg.norm_eps), new_caches
+        x, new_caches, n_here = _stack_attn(params, cfg, x, positions, dist,
+                                            decoding, caches, index)
+    return rms_norm(x, params["ln_f"], cfg.norm_eps), new_caches, n_here
 
 
 def _head(params, h):
@@ -418,7 +452,7 @@ def train_loss(params, batch: dict, cfg: ModelConfig,
     With ``cfg.loss_chunk`` the vocabulary head + xent run per sequence
     chunk inside a scan, so the (B, S, V) logits tensor (the dominant
     training memory term for 150k-vocab models) never materializes."""
-    h, _ = _forward(params, cfg, batch, dist)
+    h, _, _ = _forward(params, cfg, batch, dist)
     labels = batch["labels"]
     if cfg.loss_chunk and h.shape[1] % cfg.loss_chunk == 0 \
             and h.shape[1] > cfg.loss_chunk:
@@ -441,7 +475,7 @@ def prefill(params, batch: dict, cfg: ModelConfig, dist: Dist = Dist()):
     """Full-sequence forward; returns (last-position logits, caches of
     length S for continuation).  The vocab head runs on the LAST position
     only — serving never needs the (B, S, V) logits."""
-    h, caches = _forward(params, cfg, batch, dist)
+    h, caches, _ = _forward(params, cfg, batch, dist)
     return _head(params, h[:, -1]), caches
 
 
@@ -452,8 +486,8 @@ def decode_step(params, token_batch: dict, caches: dict, index,
     scalar shared by every row or a (B,) vector of each row's own.  Each
     row's new K/V are written at its index into ``caches`` in place, so a
     donated cache is updated without a copy."""
-    h, new_caches = _forward(params, cfg, token_batch, dist,
-                             decoding=True, caches=caches, index=index)
+    h, new_caches, _ = _forward(params, cfg, token_batch, dist,
+                                decoding=True, caches=caches, index=index)
     return _head(params, h[:, -1]), new_caches
 
 

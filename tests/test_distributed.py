@@ -84,17 +84,17 @@ def test_moe_ep_a2a_decode_matches_dense_oracle():
     """, devices=4)
 
 
-def test_moe_gather_matches_dense_oracle():
+def test_moe_share_matches_dense_oracle():
     _run("""
         import jax, jax.numpy as jnp
         from repro.models.common import ModelConfig
-        from repro.models.moe import init_moe, moe_dense, moe_gather
+        from repro.models.moe import init_moe, moe_dense, moe_share
         cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=16,
                           n_heads=2, n_kv_heads=2, d_ff=32, vocab=64,
                           n_experts=5, top_k=2, moe_d_ff=24)
         p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 16))
-        err = float(jnp.max(jnp.abs(moe_gather(p, cfg, x)
+        err = float(jnp.max(jnp.abs(moe_share(p, cfg, x)[0]
                                     - moe_dense(p, cfg, x))))
         assert err < 1e-5, err
         print("ok")

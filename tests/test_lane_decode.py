@@ -22,6 +22,7 @@ from repro.models.transformer import decode_step, init_cache, init_params
 FAMILIES = ("qwen3-0.6b",            # dense
             "granite-moe-3b-a800m",  # MoE
             "deepseek-v3-671b",      # MLA
+            "deepseek-v2-lite",      # MLA without q-LoRA, YaRN, dense lead
             "qwen2-vl-72b",          # M-RoPE
             "zamba2-1.2b",           # hybrid
             "falcon-mamba-7b")       # SSM

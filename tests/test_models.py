@@ -74,7 +74,8 @@ def test_arch_smoke_decode_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "falcon-mamba-7b",
-                                  "zamba2-1.2b", "deepseek-v3-671b"])
+                                  "zamba2-1.2b", "deepseek-v3-671b",
+                                  "deepseek-v2-lite"])
 def test_decode_matches_prefill(arch):
     """Token-by-token decode must reproduce the full-sequence logits —
     validates KV/MLA/SSM caches and (for zamba2) the shared-block caches."""
@@ -107,7 +108,7 @@ def test_runnable_cells_skip_rules():
     assert ("qwen1.5-4b", "long_500k") not in cells
     assert ("zamba2-1.2b", "long_500k") in cells
     assert ("falcon-mamba-7b", "long_500k") in cells
-    assert len(cells) == 31
+    assert len(cells) == 34
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -125,6 +126,7 @@ def test_full_config_numbers(arch):
         "qwen3-0.6b": (28, 1024, 16, 8, 151936),
         "falcon-mamba-7b": (64, 4096, 0, 0, 65024),
         "hubert-xlarge": (48, 1280, 16, 16, 504),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 102400),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
             cfg.vocab) == expected
