@@ -262,8 +262,11 @@ class ServeEngine:
     ``queue`` intervals and the ``prefill_tokens`` count; for an MoE model,
     per decode execution, ``moe_pairs`` (busy lanes x top_k x MoE layers),
     ``moe_pairs_here`` (those routed to experts held here, fetched with the
-    sampled ids) and ``moe_expert_slots`` (held experts x MoE layers).  Off
-    by default: the routed counts are then never fetched."""
+    sampled ids), ``moe_expert_slots`` (held experts x MoE layers) and, when
+    the decode computes the held experts densely (``moe.computes_densely``),
+    ``moe_rows_dense`` (lanes x held experts x MoE layers, idle lanes
+    included: the rows those products compute).  Off by default: the routed
+    counts are then never fetched."""
 
     def __init__(self, cfg, params=None, *, max_len: int = 128,
                  max_slots: int = 4, prefill_chunk: int = 2,
@@ -547,10 +550,14 @@ class ServeEngine:
         """The routed counters of one decode execution over its busy lanes
         ``active`` (``routed``: the (MoE layers, lanes) pairs routed to held
         experts; idle lanes are computed but not counted)."""
-        n_moe, busy = len(routed), [i for i, _ in active]
+        from ...models.moe import computes_densely
+        (n_moe, lanes), busy = routed.shape, [i for i, _ in active]
         self.spans.count("moe_pairs", len(busy) * cfg.top_k * n_moe)
         self.spans.count("moe_pairs_here", int(np.sum(routed[:, busy])))
         self.spans.count("moe_expert_slots", cfg.n_experts_here * n_moe)
+        if computes_densely(lanes):
+            self.spans.count("moe_rows_dense",
+                             lanes * cfg.n_experts_here * n_moe)
 
     def _decode_tick(self) -> None:
         self._decode_complete(self._decode_dispatch())

@@ -3,11 +3,14 @@
 Three execution paths:
 
 * :func:`moe_share` — the layer without a mesh, for prefill and decode alike:
-  routes every token over all ``n_experts``, keeps the (token, expert) pairs
-  whose expert this chip holds (``experts_held`` experts from ``expert_block
-  * experts_held``; all of them by default) and computes them with a
-  dropless grouped product (``lax.ragged_dot``) over pairs sorted by expert.
-  With every expert held it computes what :func:`moe_dense` computes.
+  routes every token over all ``n_experts`` and computes the (token, expert)
+  pairs whose expert this chip holds (``experts_held`` experts from
+  ``expert_block * experts_held``; all of them by default).  A call of at
+  most ``DENSE_MAX_ROWS`` rows (decode) computes every held expert for every
+  row and zeroes the pairs not routed; a larger call (prefill) computes only
+  the routed pairs, sorted by expert, with a dropless grouped product
+  (``lax.ragged_dot``).  With every expert held it computes what
+  :func:`moe_dense` computes.
 * :func:`moe_dense` — reference implementation: every expert computes every
   token, combined with the top-k gate mask.  O(E) compute — the numerical
   oracle for the other two.
@@ -33,6 +36,19 @@ from .common import ModelConfig
 from .layers import dense_init, swiglu
 
 NEG_INF = -1e30
+
+# The most rows for which moe_share computes every held expert densely.  That
+# form does n FLOPs per byte of expert weight it reads (2n per bf16 value), so
+# it stays bound by the weights' bytes while n is under the chip's ridge
+# (~240 on a TPU v5e: 197 TFLOP/s over 819 GB/s); 128 leaves margin.  Beyond
+# it the grouped product computes only the routed pairs.
+DENSE_MAX_ROWS = 128
+
+
+def computes_densely(n_rows: int) -> bool:
+    """Whether :func:`moe_share` computes every held expert for a call of
+    ``n_rows`` rows (``B * S``), rather than the routed pairs alone."""
+    return n_rows <= DENSE_MAX_ROWS
 
 
 def expert_pad(cfg: ModelConfig, n_shards: int = 1) -> int:
@@ -125,21 +141,58 @@ def moe_share(p, cfg: ModelConfig, x):
     Every token is routed over all ``n_experts``; of its ``top_k`` pairs,
     those whose expert is held here (``p["w_gate"]`` holds experts
     ``expert_block * G`` to ``expert_block * G + G - 1``, ``G`` its length)
-    are sorted by expert and computed by one dropless grouped product per
-    projection: every such pair is computed, whatever the load.  The results
-    return to their tokens weighted by the gate, in the order of the top-k
-    (so a token's output does not depend on the rest of the batch), and the
-    shared experts are added once.  Returns ``(y, n_here)``: the output and,
-    per row of ``x``, the number of its pairs routed to held experts ((B,)
-    int32)."""
+    are computed, whatever the load, and return to their tokens weighted by
+    the gate; the shared experts are added once.  A token's output depends on
+    its own row alone.  The form depends on the static row count
+    ``n = B * S``:
+
+    * ``n <= DENSE_MAX_ROWS`` (decode): one product per projection computes
+      every held expert for every row, and an (n, G) matrix of the gates
+      (0 where a row did not route to that expert) combines them.  The
+      products read the stacked weights in place and are bound by their
+      bytes (``DENSE_MAX_ROWS`` says why).
+    * otherwise (prefill): the pairs are sorted by expert and computed by
+      one dropless grouped product (``lax.ragged_dot``) per projection,
+      which does only the routed pairs' FLOPs.
+
+    Returns ``(y, n_here)``: the output and, per row of ``x``, the number of
+    its pairs routed to held experts ((B,) int32)."""
     B, S, d = x.shape
     x2 = x.reshape(-1, d)
     n, k = x2.shape[0], cfg.top_k
     G = p["w_gate"].shape[0]
     w, idx = _route(x2, p["router"], cfg)
-    local = idx.reshape(-1) - cfg.expert_block * G              # (n*k,)
+    local = idx - cfg.expert_block * G                          # (n, k)
     here = (local >= 0) & (local < G)
-    group = jnp.where(here, local, G)            # pairs not held sort last
+    gate = jnp.where(here, w, 0)
+    if computes_densely(n):
+        y = _held_dense(p, x2, local, gate)
+    else:
+        y = _held_grouped(p, x2, local, here, gate)
+    y = y + _shared(p, x2)
+    return y.reshape(B, S, d), jnp.sum(here.reshape(B, S * k), -1,
+                                       dtype=jnp.int32)
+
+
+def _held_dense(p, x2, local, gate):
+    """Every held expert on every row of x2 (n, d), combined by ``gate``
+    scattered to the rows' local expert indices (``local`` (n, k))."""
+    G = p["w_gate"].shape[0]
+    combine = jnp.einsum("nk,nke->ne", gate,
+                         jax.nn.one_hot(local, G, dtype=gate.dtype))
+    g = jax.nn.silu(jnp.einsum("nd,edf->enf", x2, p["w_gate"]))
+    u = jnp.einsum("nd,edf->enf", x2, p["w_up"])
+    ye = jnp.einsum("enf,efd->end", g * u, p["w_down"])
+    return jnp.einsum("end,ne->nd", ye, combine)
+
+
+def _held_grouped(p, x2, local, here, gate):
+    """The routed pairs of x2 (n, d) held here, sorted by expert, through
+    one grouped product per projection, returned to their rows in top-k
+    order and weighted by ``gate`` (0 for pairs not held)."""
+    n, k = local.shape
+    G = p["w_gate"].shape[0]
+    group = jnp.where(here, local, G).reshape(-1)  # pairs not held sort last
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, G, dtype=jnp.int32), axis=0)
     xs = x2[order // k]                                         # (n*k, d)
@@ -147,11 +200,8 @@ def moe_share(p, cfg: ModelConfig, x):
     u = jax.lax.ragged_dot(xs, p["w_up"], sizes)
     ys = jax.lax.ragged_dot(g * u, p["w_down"], sizes)          # rows past
     back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
-    ys = ys[back].reshape(n, k, d)                              # sizes: 0
-    gate = jnp.where(here.reshape(n, k), w, 0).astype(ys.dtype)
-    y = jnp.einsum("nk,nkd->nd", gate, ys) + _shared(p, x2)
-    return y.reshape(B, S, d), jnp.sum(here.reshape(B, S * k), -1,
-                                       dtype=jnp.int32)
+    ys = ys[back].reshape(n, k, -1)                             # sizes: 0
+    return jnp.einsum("nk,nkd->nd", gate.astype(ys.dtype), ys)
 
 
 # --------------------------------------------------------------------------

@@ -22,7 +22,8 @@ from repro.core.deploy import ServeEngine, ServeRequest
 from repro.core.deploy.engine import _jitted
 from repro.models.attention import mla_scale
 from repro.models.layers import swiglu, yarn_freqs, yarn_mscale
-from repro.models.moe import _route, init_moe, moe_dense, moe_share
+from repro.models.moe import (DENSE_MAX_ROWS, _route, init_moe, moe_dense,
+                              moe_share)
 from repro.models.transformer import (decode_step, init_cache, init_params,
                                       prefill)
 
@@ -103,18 +104,30 @@ def test_engine_matches_the_reference_through_the_lane_cache():
                                        err_msg=f"{req.uid} at {i}")
 
 
-@pytest.mark.parametrize("norm_topk_prob", [False, True])
-def test_chip_shares_add_up_to_the_uncut_layer(norm_topk_prob):
-    """16 experts as 4 shares of 4: the shares' outputs, with the shared
-    expert (which every chip computes) counted once, add up to the uncut
-    layer, and every routed pair lands in exactly one share."""
+def _sixteen_experts(**kw):
     from repro.models.common import ModelConfig
     cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=32,
                       n_heads=2, n_kv_heads=2, d_ff=64, vocab=64,
                       n_experts=16, n_shared_experts=1, top_k=4,
-                      moe_d_ff=24, norm_topk_prob=norm_topk_prob)
-    p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 32))
+                      moe_d_ff=24, **kw)
+    return cfg, init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+
+
+def _takes_grouped_form(p, cfg, x):
+    return "ragged_dot" in str(jax.make_jaxpr(
+        lambda x: moe_share(p, cfg, x))(x))
+
+
+# 10 rows take the dense form, 160 the grouped one (DENSE_MAX_ROWS is 128)
+@pytest.mark.parametrize("seq", [5, 80])
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_chip_shares_add_up_to_the_uncut_layer(norm_topk_prob, seq):
+    """16 experts as 4 shares of 4: the shares' outputs, with the shared
+    expert (which every chip computes) counted once, add up to the uncut
+    layer, and every routed pair lands in exactly one share."""
+    cfg, p = _sixteen_experts(norm_topk_prob=norm_topk_prob)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 32))
+    assert _takes_grouped_form(p, cfg, x) == (2 * seq > DENSE_MAX_ROWS)
     shared = swiglu(x, p["sh_gate"], p["sh_up"], p["sh_down"])
     parts, n_here = [], 0
     for b in range(4):
@@ -127,14 +140,54 @@ def test_chip_shares_add_up_to_the_uncut_layer(norm_topk_prob):
         n_here = n_here + np.asarray(n)
     np.testing.assert_allclose(sum(parts) + shared, moe_dense(p, cfg, x),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(n_here, [5 * 4, 5 * 4])
+    np.testing.assert_array_equal(n_here, [seq * 4, seq * 4])
+
+
+@pytest.mark.parametrize("block", [0, 3])
+def test_dense_and_grouped_forms_agree(block):
+    """The same 160 rows through the grouped form at once and through the
+    dense form in two calls of 80: a row's output and count depend on that
+    row alone, so the two forms give the same output and ``n_here``."""
+    cfg, p = _sixteen_experts()
+    cfg = cfg.scaled(experts_held=4, expert_block=block)
+    sl = slice(4 * block, 4 * block + 4)
+    p = dict(p, w_gate=p["w_gate"][sl], w_up=p["w_up"][sl],
+             w_down=p["w_down"][sl])
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 80, 32))
+    halves = x[:, :40], x[:, 40:]
+    assert _takes_grouped_form(p, cfg, x)
+    assert not any(_takes_grouped_form(p, cfg, h) for h in halves)
+    y, n = moe_share(p, cfg, x)
+    parts = [moe_share(p, cfg, h) for h in halves]
+    np.testing.assert_allclose(
+        y, jnp.concatenate([yh for yh, _ in parts], 1), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(n, sum(np.asarray(nh) for _, nh in parts))
+    assert 0 < int(np.sum(n)) < 160 * cfg.top_k
+
+
+def test_decode_computes_the_held_experts_densely_and_prefill_grouped():
+    """The served programs' jaxprs: a decode of a few lanes holds no grouped
+    product, a prefill of more than DENSE_MAX_ROWS tokens does."""
+    cfg = smoke_config("deepseek-v2-lite").scaled(experts_held=4)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pre, dec = _jitted(cfg)
+    lanes, T = 4, DENSE_MAX_ROWS + 8
+    tb = {"tokens": jnp.zeros((lanes, 1), jnp.int32),
+          "positions": jnp.zeros((lanes, 1), jnp.int32)}
+    decode = str(jax.make_jaxpr(dec)(params, tb, init_cache(cfg, lanes, T),
+                                     jnp.zeros((lanes,), jnp.int32)))
+    prompt = {"tokens": jnp.zeros((1, T), jnp.int32),
+              "positions": jnp.arange(T)[None]}
+    assert "ragged_dot" not in decode
+    assert "ragged_dot" in str(jax.make_jaxpr(pre)(params, prompt))
 
 
 @pytest.mark.parametrize("held", [0, 4])
 def test_engine_counts_the_routed_pairs_of_busy_lanes(held):
     """With a recorder on, the engine counts each decode execution's pairs
-    over its busy lanes only, though idle lanes are computed too: holding
-    every expert, every pair of every decoded token lands here."""
+    over its busy lanes only, though idle lanes are computed too (and count
+    among the dense form's rows): holding every expert, every pair of every
+    decoded token lands here."""
     from repro.core.spans import Spans
     cfg = smoke_config("deepseek-v2-lite").scaled(experts_held=held)
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -153,6 +206,9 @@ def test_engine_counts_the_routed_pairs_of_busy_lanes(held):
     assert c["moe_pairs"] == steps * cfg.top_k * n_moe
     assert c["moe_expert_slots"] == \
         eng.n_decode_batches * cfg.n_experts_here * n_moe
+    # every decode of 3 lanes takes the dense form, idle lanes included
+    assert c["moe_rows_dense"] == \
+        eng.n_decode_batches * 3 * cfg.n_experts_here * n_moe
     if held:
         assert 0 < c["moe_pairs_here"] < c["moe_pairs"]
     else:
