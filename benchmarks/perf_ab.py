@@ -594,7 +594,9 @@ def serving_ab(generations: int = 2, seed: int = 0,
         rec = {"schedule": schedule,
                "throughput_tok_s": med,
                "runs_tok_s": [r["throughput_tok_s"] for r in runs],
-               "per_variant": runs[0]["per_variant"],
+               **{k: runs[0][k] for k in ("mean_latency_s",
+                                          "p95_latency_s", "mean_ttft_s",
+                                          "s_per_token")},
                "decode_batches": runs[0]["decode_batches"]}
         print(f"[serving_ab] {tag}: {schedule} -> {med:.1f} tok/s "
               f"(runs {rec['runs_tok_s']})")

@@ -135,7 +135,8 @@ def _checked_engine_cls():
             return super()._sample(logits, routed)
 
         def _decode_complete(self, pending):
-            for _, active, logits, _ in pending:
+            if pending is not None:
+                active, logits, _ = pending
                 for i, lane in active:
                     self._keep(lane.req.uid, logits[i])
             super()._decode_complete(pending)
@@ -365,8 +366,7 @@ def phase_router(seed: int, clock: _CompileClock) -> None:
     homes = []
     for r in router.replicas:
         eng = r.engine
-        devs = _placement(eng.params) | _placement(
-            [b.caches for b in eng.batches.values()])
+        devs = _placement(eng.params) | _placement(eng.batch.caches)
         _require(len(devs) == 1, f"replica {r.index} spans {devs}")
         homes.append(devs.pop())
     _require(len(set(homes)) == n, f"replicas share devices: {homes}")

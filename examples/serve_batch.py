@@ -59,11 +59,10 @@ def main():
                               prompt_len=args.prompt_len, gen=args.gen)
         results = engine.run(trace, stagger=args.stagger or None)
         s = engine.stats()
-        rec = s["per_variant"]["default"]
         print(f"  {len(results)} requests in {s['wall_s']:.2f}s "
               f"({s['throughput_tok_s']:.1f} tok/s, "
-              f"ttft {rec['mean_ttft_s'] * 1e3:.0f}ms, "
-              f"latency {rec['mean_latency_s'] * 1e3:.0f}ms, "
+              f"ttft {s['mean_ttft_s'] * 1e3:.0f}ms, "
+              f"latency {s['mean_latency_s'] * 1e3:.0f}ms, "
               f"{s['decode_batches']} decode dispatches)")
         # continuous batching must reproduce the one-shot oracle exactly
         probe = trace[0]

@@ -143,7 +143,9 @@ def _fold_one(op: Operation, consts: dict[int, np.ndarray]
         out = _FOLD_COMPARE[a["direction"]](xs[0], xs[1])
     if out is None:
         return None
-    out = np.ascontiguousarray(out, dtype=_NP_DTYPE[op.type.dtype])
+    # a C-ordered copy that keeps a 0-d result 0-d (``ascontiguousarray``
+    # would give a scalar shape (1,), against the op's recorded type)
+    out = np.array(out, dtype=_NP_DTYPE[op.type.dtype], order="C")
     if out.dtype.kind == "f" and not np.all(np.isfinite(out)):
         return None   # inf/nan folds risk semantic drift; leave to runtime
     return out

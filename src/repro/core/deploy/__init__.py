@@ -11,8 +11,8 @@ turns a recorded front into served traffic:
   written winner manifests keyed by ``(kind, name, shape)``, with
   byte-exact round-trips and verified resolution;
 * :class:`ServeEngine` — the continuous-batching serving loop (request
-  queue, micro-batched prefill + decode interleaving, default/evolved
-  variant routing, measured latency fed back into the shared
+  queue, micro-batched prefill + decode interleaving, measured latency
+  fed back into the shared
   :class:`~repro.core.evaluator.FitnessCache` under a ``serve`` tag);
 * :class:`KVPlan` (:mod:`~repro.core.deploy.kvplan`) — the KV memory plan
   (page size, cache dtype, replica layout) as searchable genome knobs
@@ -29,7 +29,7 @@ end-to-end walkthroughs.
 from .engine import (DEFAULT_ENGINE_SCHEDULE, DEFAULT_SERVE_PLAN,
                      ENGINE_SPACE, SERVE_PLAN_KEYS, SERVE_SPACE,
                      ServeEngine, ServeRequest, ServeResult,
-                     apply_plan_artifact, build_serve_workload, demo_trace,
+                     apply_plan_artifact, build_serve_workload,
                      engine_schedule_from, oneshot_generate,
                      serve_plan_from, serve_schedule_space)
 from .front import FrontMember, ParetoFront
@@ -44,7 +44,7 @@ __all__ = [
     "Artifact", "ArtifactRegistry", "shape_tag",
     "ServeEngine", "ServeRequest", "ServeResult",
     "apply_plan_artifact", "engine_schedule_from", "serve_plan_from",
-    "oneshot_generate", "demo_trace", "build_serve_workload",
+    "oneshot_generate", "build_serve_workload",
     "serve_schedule_space",
     "SERVE_SPACE", "ENGINE_SPACE", "SERVE_PLAN_KEYS",
     "DEFAULT_ENGINE_SCHEDULE", "DEFAULT_SERVE_PLAN",

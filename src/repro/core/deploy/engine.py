@@ -13,12 +13,8 @@ this repo's smoke configs but shaped like the real thing:
   requests (grouped by prompt length, so prefill batches are pad-free) and
   advances every in-flight sequence one token (grouped by cache position,
   so grouped decode is numerically identical to lockstep decode);
-* **per-variant routing** — requests route to the ``default`` model
-  configuration or to an ``evolved`` one (a distribution-plan artifact's
-  serve-relevant knobs applied via ``cfg.scaled``), with an A/B fraction,
-  so an evolved winner can take traffic gradually;
 * **measured latency feedback** — per-request TTFT / latency / tokens, and
-  :meth:`publish_stats` writes per-variant (s/token, mean latency) records
+  :meth:`publish_stats` writes an (s/token, mean latency) record
   into the shared :class:`~repro.core.evaluator.FitnessCache` under a
   ``serve`` writer tag — the serving fleet reports fitness into the same
   store the search reads.
@@ -44,7 +40,6 @@ from __future__ import annotations
 import hashlib
 import json
 import time as _time
-import warnings
 from collections import deque
 from dataclasses import dataclass, field
 from functools import lru_cache
@@ -123,23 +118,20 @@ def serve_plan_from(artifact: Artifact | None) -> dict:
 @dataclass
 class ServeRequest:
     """One generation request: a prompt (1-D int token array) and a token
-    budget.  ``variant`` pins the route (``"default"``/``"evolved"``);
-    ``None`` lets the engine's A/B fraction decide."""
+    budget."""
 
     uid: str
     tokens: np.ndarray
     max_new_tokens: int = 16
     eos_id: int | None = None
-    variant: str | None = None
 
 
 @dataclass
 class ServeResult:
-    """A completed request: generated tokens, the route it took, and its
-    measured timeline (submit -> admit -> first token -> done)."""
+    """A completed request: generated tokens and its measured timeline
+    (submit -> admit -> first token -> done)."""
 
     uid: str
-    variant: str
     tokens: list[int] = field(default_factory=list)
     t_submit: float = 0.0
     t_admit: float = 0.0
@@ -155,9 +147,48 @@ class ServeResult:
         return self.t_first - self.t_submit
 
 
+def latency_summary(results: list[ServeResult]) -> dict:
+    """Count, generated tokens, mean and p95 latency, mean TTFT and
+    seconds per generated token of finished requests; zeros for none."""
+    if not results:
+        return {"n": 0, "gen_tokens": 0, "mean_latency_s": 0.0,
+                "p95_latency_s": 0.0, "mean_ttft_s": 0.0,
+                "s_per_token": 0.0}
+    lat = np.array([r.latency for r in results])
+    toks = sum(len(r.tokens) for r in results)
+    return {"n": len(results),
+            "gen_tokens": toks,
+            "mean_latency_s": round(float(lat.mean()), 6),
+            "p95_latency_s": round(float(np.percentile(lat, 95)), 6),
+            "mean_ttft_s": round(
+                float(np.mean([r.ttft for r in results])), 6),
+            "s_per_token": round(float(lat.sum() / max(toks, 1)), 6)}
+
+
+def publish_latency(cache: FitnessCache, stats: dict, body: dict, *,
+                    features=None, meta: dict | None = None) -> list[str]:
+    """Write the fitness ``(s_per_token, mean_latency_s)`` of ``stats`` to
+    ``cache`` as a ``serve``-tagged record keyed by the hash of ``body``.
+    Returns the key, or nothing when nothing completed (a zero measurement
+    is not a measurement) or the key is already there (first measurement
+    wins)."""
+    if cache.writer is None:
+        cache.writer = "serve"
+    if stats["n_completed"] == 0:
+        return []
+    key = "serve:" + hashlib.sha256(
+        json.dumps(body, sort_keys=True).encode()).hexdigest()
+    if key in cache:
+        return []
+    cache.put(key, EvalOutcome(
+        fitness=(stats["s_per_token"], stats["mean_latency_s"])),
+        features=features, meta=meta)
+    return [key]
+
+
 @dataclass
 class _Lane:
-    """One resident sequence in a variant's lane batch."""
+    """One resident sequence in the lane batch."""
     req: ServeRequest
     index: int                      # current cache length (next write pos)
     tokens: list[int]
@@ -166,11 +197,11 @@ class _Lane:
 
 
 class _LaneBatch:
-    """A variant's fixed-width continuous batch: ``n_lanes`` resident
+    """The engine's fixed-width continuous batch: ``n_lanes`` resident
     sequences sharing ONE cache whose batch axis is the lane
     (``init_cache(cfg, n_lanes, max_len)``), advanced by a single decode
     dispatch per tick with a per-lane cache index.  Lane shapes never
-    change, so decode compiles exactly once per variant; a finished lane's
+    change, so decode compiles exactly once; a finished lane's
     cache is simply overwritten at the next admission."""
 
     def __init__(self, n_lanes: int):
@@ -243,12 +274,12 @@ def _batch_axis_slice(caches: dict, i: int):
 class ServeEngine:
     """Continuous-batching serving over one model's parameters.
 
-    ``cfg`` is the default-route :class:`~repro.models.common.ModelConfig`;
-    ``evolved_cfg`` (optional, same parameter shapes) is the evolved route,
-    taking ``ab_fraction`` of unpinned requests.  ``params=None`` initializes
-    random weights (the smoke/demo path).  ``max_len`` bounds
-    ``prompt + generation`` per request; every slot cache is allocated at
-    ``max_len`` so any group of slots can decode together.
+    ``cfg`` is the served :class:`~repro.models.common.ModelConfig` (for
+    an evolved plan, :func:`apply_plan_artifact`'s result).
+    ``params=None`` initializes random weights (the smoke/demo path).
+    ``max_len`` bounds ``prompt + generation`` per request; every slot
+    cache is allocated at ``max_len`` so any group of slots can decode
+    together.
 
     ``admit_max_wait`` bounds admission reordering: the prompt-length
     grouping below prefers same-length prefill batches, but any request
@@ -270,7 +301,6 @@ class ServeEngine:
 
     def __init__(self, cfg, params=None, *, max_len: int = 128,
                  max_slots: int = 4, prefill_chunk: int = 2,
-                 evolved_cfg=None, ab_fraction: float = 0.0,
                  temperature: float = 0.0, seed: int = 0,
                  admit_max_wait: int = 32, spans: Spans | None = None):
         import jax
@@ -281,22 +311,18 @@ class ServeEngine:
         if admit_max_wait < 1:
             raise ValueError("admit_max_wait must be >= 1")
         self.admit_max_wait = admit_max_wait
-        self.cfgs = {"default": cfg}
-        if evolved_cfg is not None:
-            self.cfgs["evolved"] = evolved_cfg
-        self.ab_fraction = ab_fraction
+        self.cfg = cfg
         self.max_len = max_len
         self.max_slots = max_slots
         self.prefill_chunk = prefill_chunk
         self.temperature = temperature
-        self._route_rng = np.random.default_rng(seed)
         self._sample_key = jax.random.PRNGKey(seed + 1)
         if params is None:
             from ...models.transformer import init_params
             params = init_params(cfg, jax.random.PRNGKey(0))
         self.params = params
         self.queue: deque[ServeRequest] = deque()
-        self.batches = {v: _LaneBatch(max_slots) for v in self.cfgs}
+        self.batch = _LaneBatch(max_slots)
         self.completed: list[ServeResult] = []
         self.n_rejected = 0
         self._t0: float | None = None
@@ -306,6 +332,19 @@ class ServeEngine:
         self.n_decode_batches = 0
         self.spans = OFF if spans is None else spans
         self._t_tick = self._t_dispatch = None
+
+    @property
+    def batches(self) -> dict[str, _LaneBatch]:
+        """``{"default": self.batch}``, read by the chip benchmark's serving
+        entry and fault tests (``benchmarks/chip/``); it goes once those
+        read ``batch``."""
+        return {"default": self.batch}
+
+    @property
+    def cfgs(self) -> dict:
+        """``{"default": self.cfg}``, read by the chip benchmark's fault
+        tests; it goes once those read ``cfg``."""
+        return {"default": self.cfg}
 
     # -- submission ----------------------------------------------------------
     def submit(self, req: ServeRequest, *,
@@ -318,9 +357,6 @@ class ServeEngine:
                 f"request {req.uid}: prompt {len(tokens)} + "
                 f"{req.max_new_tokens} new tokens exceeds max_len "
                 f"{self.max_len}")
-        if req.variant is not None and req.variant not in self.cfgs:
-            raise ValueError(f"request {req.uid}: unknown variant "
-                             f"{req.variant!r} (have {list(self.cfgs)})")
         req.tokens = tokens
         req._t_submit = _time.perf_counter() if t_submit is None \
             else t_submit
@@ -329,7 +365,7 @@ class ServeEngine:
 
     def try_submit(self, req: ServeRequest) -> bool:
         """Admission-or-reject: like :meth:`submit` but malformed requests
-        (over-budget prompt, unknown variant) are *counted*, not raised — a
+        (over-budget prompt) are *counted*, not raised — a
         live replay loop must survive bad traffic.  Returns whether the
         request was accepted."""
         try:
@@ -343,28 +379,16 @@ class ServeEngine:
         for r in reqs:
             self.submit(r)
 
-    # -- routing -------------------------------------------------------------
-    def _route(self, req: ServeRequest) -> str:
-        if req.variant is not None:
-            return req.variant
-        if "evolved" in self.cfgs and \
-                self._route_rng.random() < self.ab_fraction:
-            return "evolved"
-        return "default"
-
     # -- prefill (admission) -------------------------------------------------
-    def _token_batch(self, cfg, tokens_2d, positions_2d):
+    def _token_batch(self, tokens_2d, positions_2d):
         # host arrays: jit transfers them straight to the device(s) holding
         # this engine's parameters, never by way of the default device
         b = {"tokens": np.asarray(tokens_2d, np.int32),
              "positions": np.asarray(positions_2d, np.int32)}
-        if cfg.mrope:
+        if self.cfg.mrope:
             b["positions3"] = np.broadcast_to(
                 b["positions"][:, :, None], positions_2d.shape + (3,))
         return b
-
-    def _n_in_flight(self) -> int:
-        return sum(b.n_active() for b in self.batches.values())
 
     def _select_admissions(self, n_take: int) -> list[ServeRequest]:
         """Pick ``n_take`` queued requests for this tick's prefill.
@@ -399,7 +423,7 @@ class ServeEngine:
         return take
 
     def _admit(self) -> None:
-        n_free = self.max_slots - self._n_in_flight()
+        n_free = self.max_slots - self.batch.n_active()
         n_take = min(n_free, self.prefill_chunk, len(self.queue))
         if n_take <= 0:
             return
@@ -412,16 +436,14 @@ class ServeEngine:
 
         from ...models.transformer import init_cache
         spans = self.spans
+        cfg, batch = self.cfg, self.batch
+        pre_fn, _ = _jitted(cfg)
         admitted = self._select_admissions(n_take)
         t_admit = _time.perf_counter()
-        groups: dict[tuple, list[ServeRequest]] = {}
+        groups: dict[int, list[ServeRequest]] = {}
         for req in admitted:
-            groups.setdefault((self._route(req), len(req.tokens)),
-                              []).append(req)
-        for (variant, plen), reqs in groups.items():
-            cfg = self.cfgs[variant]
-            batch = self.batches[variant]
-            pre_fn, _ = _jitted(cfg)
+            groups.setdefault(len(req.tokens), []).append(req)
+        for plen, reqs in groups.items():
             G = len(reqs)
             toks = np.stack([r.tokens for r in reqs])
             pos = np.broadcast_to(np.arange(plen, dtype=np.int32)[None],
@@ -429,7 +451,7 @@ class ServeEngine:
             with spans.span("prefill"):
                 spans.count("prefill_tokens", G * plen)
                 logits, pre_caches = pre_fn(
-                    self.params, self._token_batch(cfg, toks, pos))
+                    self.params, self._token_batch(toks, pos))
                 self.n_prefill_batches += 1
                 first = self._sample(logits)
             t_first = _time.perf_counter()
@@ -461,7 +483,7 @@ class ServeEngine:
                     one = jax.tree.map(splice, full, mine)
                     tok = int(first[i])
                     res = ServeResult(
-                        uid=req.uid, variant=variant,
+                        uid=req.uid,
                         t_submit=getattr(req, "_t_submit", t_admit),
                         t_admit=t_admit, t_first=t_first)
                     spans.record("queue", res.t_submit, res.t_admit)
@@ -489,27 +511,22 @@ class ServeEngine:
             ids, routed = jax.device_get((ids, routed))
             return np.asarray(ids).astype(np.int32), routed
 
-    def _decode_dispatch(self) -> list[tuple]:
-        """Phase 1 of a decode tick: launch ONE decode dispatch per
-        active variant and return the in-flight ``(variant, active,
-        logits)`` work items *without* blocking on the results — a router
-        interleaves dispatches across replicas so each replica's compute
-        overlaps its siblings' host work."""
+    def _decode_dispatch(self) -> tuple | None:
+        """Phase 1 of a decode tick: launch the decode dispatch and return
+        the in-flight ``(active, logits, routed)`` item *without* blocking
+        on the results (None when no lane is busy) — a router interleaves
+        dispatches across replicas so each replica's compute overlaps its
+        siblings' host work."""
         with self.spans.span("dispatch") as self._t_dispatch:
-            return self._dispatch_variants()
-
-    def _dispatch_variants(self) -> list[tuple]:
-        pending = []
-        for variant in sorted(self.batches):
-            batch = self.batches[variant]
+            batch = self.batch
             active = batch.active()
             if not active:
-                continue
-            cfg = self.cfgs[variant]
+                return None
+            cfg = self.cfg
             _, dec_fn = _jitted(cfg)
-            # ONE fixed-shape dispatch over every lane of this variant
-            # (idle lanes run at index 0 and are ignored; their cache is
-            # rewritten wholesale at the next admission)
+            # ONE fixed-shape dispatch over every lane (idle lanes run at
+            # index 0 and are ignored; their cache is rewritten wholesale at
+            # the next admission)
             N = batch.n_lanes
             toks = np.zeros((N, 1), np.int32)
             idx = np.zeros((N,), np.int32)
@@ -523,34 +540,35 @@ class ServeEngine:
             logits, batch.caches, routed = dec_fn(self.params, tb,
                                                   batch.caches, idx)
             self.n_decode_batches += 1
-            pending.append((variant, active, logits, routed))
-        return pending
+            return active, logits, routed
 
-    def _decode_complete(self, pending: list[tuple]) -> None:
+    def _decode_complete(self, pending: tuple | None) -> None:
         """Phase 2 of a decode tick: sample next tokens (this is where the
         host blocks on device results) and advance lane bookkeeping."""
-        for variant, active, logits, routed in pending:
-            batch = self.batches[variant]
-            if routed is not None and self.spans is not OFF:
-                nxt, routed = self._sample(logits, routed)
-                self._count_routed(self.cfgs[variant], active, routed)
-            else:
-                nxt = self._sample(logits)
-            t_now = _time.perf_counter()
-            self.spans.record("decode", self._t_dispatch, t_now)
-            for i, lane in active:
-                lane.index += 1
-                tok = int(nxt[i])
-                lane.tokens.append(tok)
-                lane.last = tok
-                if self._maybe_finish(lane, t_now):
-                    batch.lanes[i] = None
+        if pending is None:
+            return
+        active, logits, routed = pending
+        if routed is not None and self.spans is not OFF:
+            nxt, routed = self._sample(logits, routed)
+            self._count_routed(active, routed)
+        else:
+            nxt = self._sample(logits)
+        t_now = _time.perf_counter()
+        self.spans.record("decode", self._t_dispatch, t_now)
+        for i, lane in active:
+            lane.index += 1
+            tok = int(nxt[i])
+            lane.tokens.append(tok)
+            lane.last = tok
+            if self._maybe_finish(lane, t_now):
+                self.batch.lanes[i] = None
 
-    def _count_routed(self, cfg, active, routed) -> None:
+    def _count_routed(self, active, routed) -> None:
         """The routed counters of one decode execution over its busy lanes
         ``active`` (``routed``: the (MoE layers, lanes) pairs routed to held
         experts; idle lanes are computed but not counted)."""
         from ...models.moe import computes_densely
+        cfg = self.cfg
         (n_moe, lanes), busy = routed.shape, [i for i, _ in active]
         self.spans.count("moe_pairs", len(busy) * cfg.top_k * n_moe)
         self.spans.count("moe_pairs_here", int(np.sum(routed[:, busy])))
@@ -576,9 +594,9 @@ class ServeEngine:
     # -- the loop ------------------------------------------------------------
     @property
     def busy(self) -> bool:
-        return bool(self.queue) or self._n_in_flight() > 0
+        return bool(self.queue) or self.batch.n_active() > 0
 
-    def begin_step(self) -> list[tuple]:
+    def begin_step(self) -> tuple | None:
         """The first half of a tick: admit + micro-batch prefill new
         requests, then *dispatch* (without blocking) the decode batch.
         Callers that drive several engines — the multi-replica router —
@@ -591,7 +609,7 @@ class ServeEngine:
         self._admit()
         return self._decode_dispatch()
 
-    def finish_step(self, pending: list[tuple]) -> None:
+    def finish_step(self, pending: tuple | None) -> None:
         """The second half of a tick: block on the dispatched decode,
         sample, and retire finished lanes."""
         self._decode_complete(pending)
@@ -621,89 +639,49 @@ class ServeEngine:
 
     # -- stats + feedback ----------------------------------------------------
     def stats(self) -> dict:
-        """Aggregate measured serving stats, overall and per variant.
-        Total on every path the live loop hits: before the first tick,
-        mid-run before any completion, and after all-rejected admissions
-        the numbers are well-defined zeros, never negative and never a
-        raise.  Variants that completed nothing still get a zeroed row (so
-        canary guardrails can read ``per_variant["evolved"]["n"] == 0``
-        instead of catching ``KeyError``)."""
+        """Aggregate measured serving stats, with :func:`latency_summary`'s
+        keys at the top level.  Total on every path the live loop hits:
+        before the first tick, mid-run before any completion, and after
+        all-rejected admissions the numbers are well-defined zeros, never
+        negative and never a raise."""
         # _t_last stays 0.0 until the first completion, so a mid-run read
         # would see a negative span; clamp to "no completed work yet".
         wall = max(self._t_last - self._t0, 0.0) \
             if self._t0 is not None else 0.0
-        out = {"n_completed": len(self.completed),
+        summary = latency_summary(self.completed)
+        out = {"n_completed": summary.pop("n"),
                "n_rejected": self.n_rejected,
                "wall_s": round(wall, 6),
                "ticks": self.n_ticks,
                "prefill_batches": self.n_prefill_batches,
                "decode_batches": self.n_decode_batches,
-               "gen_tokens": sum(len(r.tokens) for r in self.completed),
-               "per_variant": {}}
+               **summary}
         out["throughput_tok_s"] = round(
             out["gen_tokens"] / wall, 3) if wall > 0 else 0.0
-        for variant in self.cfgs:
-            rs = [r for r in self.completed if r.variant == variant]
-            if not rs:
-                out["per_variant"][variant] = {
-                    "n": 0, "gen_tokens": 0, "mean_latency_s": 0.0,
-                    "p95_latency_s": 0.0, "mean_ttft_s": 0.0,
-                    "s_per_token": 0.0}
-                continue
-            lat = np.array([r.latency for r in rs])
-            toks = sum(len(r.tokens) for r in rs)
-            out["per_variant"][variant] = {
-                "n": len(rs),
-                "gen_tokens": toks,
-                "mean_latency_s": round(float(lat.mean()), 6),
-                "p95_latency_s": round(float(np.percentile(lat, 95)), 6),
-                "mean_ttft_s": round(
-                    float(np.mean([r.ttft for r in rs])), 6),
-                "s_per_token": round(float(lat.sum() / max(toks, 1)), 6),
-            }
         return out
 
     def publish_stats(self, cache: FitnessCache, *, name: str, shape,
                       run: str = "", features=None,
                       meta: dict | None = None) -> list[str]:
-        """Feed measured per-variant serving fitness back into a shared
-        :class:`FitnessCache` as ``serve``-tagged records (fitness =
-        ``(s_per_token, mean_latency_s)``).  The key is a content hash of
-        the measurement configuration — arch, shape, variant, AND the
-        engine schedule — so measurements under different schedules never
-        collide; like every cache record, a key already present is left
-        untouched (first measurement wins), so pass a distinct ``run`` tag
-        to record repeated measurements of the same configuration.
-        Returns the keys of records actually added (empty if everything
-        was already recorded).  Searches warm-starting from the same store
-        see what deployment measured.
-
-        ``features`` (a numeric vector, e.g. ``ScheduleFeaturizer.
-        of_genome(schedule)``) makes the records *surrogate training
-        rows*; ``meta`` (e.g. a :meth:`~repro.core.liveloop.traces.Trace.
-        spec`) rides along on the record so live traffic can later be
-        re-synthesized from the store.  Variants that completed nothing
-        are skipped — a zero measurement is not a measurement."""
-        if cache.writer is None:
-            cache.writer = "serve"
-        added = []
-        for variant, rec in self.stats()["per_variant"].items():
-            if rec["n"] == 0:
-                continue
-            body = {"kind": "serve_latency", "name": name,
-                    "shape": shape_tag(shape), "variant": variant,
-                    "schedule": {"max_slots": self.max_slots,
-                                 "prefill_chunk": self.prefill_chunk},
-                    "run": run}
-            key = "serve:" + hashlib.sha256(
-                json.dumps(body, sort_keys=True).encode()).hexdigest()
-            if key in cache:
-                continue
-            cache.put(key, EvalOutcome(
-                fitness=(rec["s_per_token"], rec["mean_latency_s"])),
-                features=features, meta=meta)
-            added.append(key)
-        return added
+        """Publish the measured fitness as a ``serve``-tagged record
+        (:func:`publish_latency`) keyed by arch, shape AND the engine
+        schedule, so measurements under different schedules never collide;
+        pass a distinct ``run`` tag to record a repeated measurement of the
+        same configuration.  Searches warm-starting from the same store see
+        what deployment measured.  ``features`` (a numeric vector, e.g.
+        ``ScheduleFeaturizer.of_genome(schedule)``) makes the record a
+        *surrogate training row*; ``meta`` (e.g. a
+        :meth:`~repro.core.liveloop.traces.Trace.spec`) rides along so live
+        traffic can later be re-synthesized from the store."""
+        # "variant" stays in the key so that stores written when the engine
+        # had a second route keep de-duplicating against new records
+        body = {"kind": "serve_latency", "name": name,
+                "shape": shape_tag(shape), "variant": "default",
+                "schedule": {"max_slots": self.max_slots,
+                             "prefill_chunk": self.prefill_chunk},
+                "run": run}
+        return publish_latency(cache, self.stats(), body,
+                               features=features, meta=meta)
 
 
 # --------------------------------------------------------------------------
@@ -730,22 +708,6 @@ def oneshot_generate(cfg, params, prompts: np.ndarray, gen: int,
     results = {r.uid: r for r in engine.run(reqs)}
     return np.array([results[f"r{i}"].tokens for i in range(len(prompts))],
                     np.int32)
-
-
-def demo_trace(cfg, *, n_requests: int, prompt_len: int, gen: int,
-               seed: int = 0) -> list[ServeRequest]:
-    """Deprecated: trace synthesis moved to ``repro.core.liveloop.traces``
-    (:func:`~repro.core.liveloop.traces.demo_requests` is this function;
-    :func:`~repro.core.liveloop.traces.synthesize` builds the richer
-    scenario shapes).  This shim emits the same request list byte-for-byte
-    and will be removed."""
-    warnings.warn(
-        "repro.core.deploy.demo_trace is deprecated; use "
-        "repro.core.liveloop.traces.demo_requests (or synthesize) instead",
-        DeprecationWarning, stacklevel=2)
-    from ..liveloop.traces import demo_requests
-    return demo_requests(cfg, n_requests=n_requests, prompt_len=prompt_len,
-                         gen=gen, seed=seed)
 
 
 def build_serve_workload(arch: str = "qwen3-0.6b", *, smoke: bool = True,
@@ -780,9 +742,7 @@ def build_serve_workload(arch: str = "qwen3-0.6b", *, smoke: bool = True,
                                  prompt_len=prompt_len, gen=gen, seed=seed),
                    stagger=stagger)
         s = engine.stats()
-        per = s["per_variant"]["default"]
-        return (s["wall_s"] / max(s["gen_tokens"], 1),
-                per["mean_latency_s"])
+        return (s["wall_s"] / max(s["gen_tokens"], 1), s["mean_latency_s"])
 
     return KernelWorkload(
         name=f"serve/{arch}",

@@ -35,7 +35,6 @@ demonstrate the failover path).
 
 from __future__ import annotations
 
-import hashlib
 import json
 import time as _time
 from collections import deque
@@ -43,9 +42,10 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..evaluator import EvalOutcome, FitnessCache
+from ..evaluator import FitnessCache
 from ..spans import Spans
-from .engine import DEFAULT_SERVE_PLAN, ServeEngine, ServeRequest
+from .engine import (DEFAULT_SERVE_PLAN, ServeEngine, ServeRequest,
+                     latency_summary, publish_latency)
 from .kvplan import KVPlan
 from .registry import shape_tag
 
@@ -115,10 +115,9 @@ class Router:
         eng = r.engine
         requeue = list(eng.queue)
         eng.queue.clear()
-        for batch in eng.batches.values():
-            for i, lane in batch.active():
-                requeue.append(lane.req)    # restart from the prompt
-                batch.lanes[i] = None
+        for i, lane in eng.batch.active():
+            requeue.append(lane.req)        # restart from the prompt
+            eng.batch.lanes[i] = None
         self.n_requeued += len(requeue)
         for req in reversed(requeue):       # preserve FIFO at the front
             self.queue.appendleft(req)
@@ -131,10 +130,6 @@ class Router:
                 f"request {req.uid}: prompt {len(tokens)} + "
                 f"{req.max_new_tokens} new tokens exceeds max_len "
                 f"{self.max_len}")
-        variants = self.replicas[0].engine.cfgs
-        if req.variant is not None and req.variant not in variants:
-            raise ValueError(f"request {req.uid}: unknown variant "
-                             f"{req.variant!r} (have {list(variants)})")
         req.tokens = tokens
         req._t_submit = _time.perf_counter()
         self.queue.append(req)
@@ -160,7 +155,7 @@ class Router:
         while self.queue:
             req = self.queue.popleft()
             r = min(live, key=lambda x: (len(x.engine.queue)
-                                         + x.engine._n_in_flight(),
+                                         + x.engine.batch.n_active(),
                                          x.index))
             r.engine.submit(req, t_submit=req._t_submit)
 
@@ -231,22 +226,24 @@ class Router:
 
     # -- stats + feedback --------------------------------------------------
     def stats(self) -> dict:
-        """Aggregate + per-replica serving stats.  Same zero-safe contract
-        as :meth:`ServeEngine.stats`: well-defined before the first tick,
-        mid-run, and after faults."""
+        """Aggregate + per-replica serving stats, with
+        :func:`~repro.core.deploy.engine.latency_summary`'s keys at the top
+        level.  Same zero-safe contract as :meth:`ServeEngine.stats`:
+        well-defined before the first tick, mid-run, and after faults."""
         t_last = max((r.engine._t_last for r in self.replicas), default=0.0)
         wall = max(t_last - self._t0, 0.0) if self._t0 is not None else 0.0
         engine_rejects = sum(r.engine.n_rejected for r in self.replicas)
-        out = {"n_completed": len(self.completed),
+        summary = latency_summary(self.completed)
+        out = {"n_completed": summary.pop("n"),
                "n_rejected": self.n_rejected + engine_rejects,
                "n_requeued": self.n_requeued,
                "n_replicas": len(self.replicas),
                "n_live": self.n_live,
                "wall_s": round(wall, 6),
                "ticks": self.n_ticks,
-               "gen_tokens": sum(len(res.tokens) for res in self.completed),
+               **summary,
                "plan": self.plan.to_genome(),
-               "per_replica": [], "per_variant": {}}
+               "per_replica": []}
         out["throughput_tok_s"] = round(
             out["gen_tokens"] / wall, 3) if wall > 0 else 0.0
         for r in self.replicas:
@@ -259,55 +256,25 @@ class Router:
                 "ticks": s["ticks"],
                 "prefill_batches": s["prefill_batches"],
                 "decode_batches": s["decode_batches"]})
-        for variant in self.replicas[0].engine.cfgs:
-            rs = [res for res in self.completed if res.variant == variant]
-            if not rs:
-                out["per_variant"][variant] = {
-                    "n": 0, "gen_tokens": 0, "mean_latency_s": 0.0,
-                    "p95_latency_s": 0.0, "mean_ttft_s": 0.0,
-                    "s_per_token": 0.0}
-                continue
-            lat = np.array([res.latency for res in rs])
-            toks = sum(len(res.tokens) for res in rs)
-            out["per_variant"][variant] = {
-                "n": len(rs),
-                "gen_tokens": toks,
-                "mean_latency_s": round(float(lat.mean()), 6),
-                "p95_latency_s": round(float(np.percentile(lat, 95)), 6),
-                "mean_ttft_s": round(
-                    float(np.mean([res.ttft for res in rs])), 6),
-                "s_per_token": round(float(lat.sum() / max(toks, 1)), 6),
-            }
         return out
 
     def publish_stats(self, cache: FitnessCache, *, name: str, shape,
                       run: str = "", features=None,
                       meta: dict | None = None) -> list[str]:
-        """Per-variant serve-tagged fitness records for the router's
-        measurement, keyed by the FULL serving plan (engine schedule + KV
-        plan + replica layout) so single-engine and multi-replica
-        measurements of the same arch never collide.  First write wins,
-        like every cache record."""
-        if cache.writer is None:
-            cache.writer = "serve"
-        added = []
-        for variant, rec in self.stats()["per_variant"].items():
-            if rec["n"] == 0:
-                continue
-            body = {"kind": "serve_latency", "name": name,
-                    "shape": shape_tag(shape), "variant": variant,
-                    "schedule": dict(self.genome),
-                    "n_replicas": len(self.replicas),
-                    "run": run}
-            key = "serve:" + hashlib.sha256(
-                json.dumps(body, sort_keys=True).encode()).hexdigest()
-            if key in cache:
-                continue
-            cache.put(key, EvalOutcome(
-                fitness=(rec["s_per_token"], rec["mean_latency_s"])),
-                features=features, meta=meta)
-            added.append(key)
-        return added
+        """A serve-tagged fitness record for the router's measurement,
+        keyed by the FULL serving plan (engine schedule + KV plan + replica
+        layout) so single-engine and multi-replica measurements of the same
+        arch never collide (see :func:`~repro.core.deploy.engine.
+        publish_latency`)."""
+        # "variant" stays in the key so that stores written when the engine
+        # had a second route keep de-duplicating against new records
+        body = {"kind": "serve_latency", "name": name,
+                "shape": shape_tag(shape), "variant": "default",
+                "schedule": dict(self.genome),
+                "n_replicas": len(self.replicas),
+                "run": run}
+        return publish_latency(cache, self.stats(), body,
+                               features=features, meta=meta)
 
 
 # --------------------------------------------------------------------------
@@ -349,7 +316,7 @@ def shard_replica_params(params, submesh):
 
 
 def shard_engine_caches(engine: ServeEngine, submesh) -> None:
-    """Pre-allocate every variant's lane cache sharded over the replica's
+    """Pre-allocate the engine's lane cache sharded over the replica's
     submesh per ``cache_specs`` (the lanes are the cache's batch dim), so
     decode runs sharded from the first tick instead of inheriting placement
     from the first admission."""
@@ -358,18 +325,16 @@ def shard_engine_caches(engine: ServeEngine, submesh) -> None:
     from ...launch.shardings import cache_specs, to_shardings
     from ...models.transformer import init_cache
     dp_axes, model_axis, dp_size, model_size = _mesh_sizes(submesh)
-    for variant, cfg in engine.cfgs.items():
-        lanes = init_cache(cfg, engine.max_slots, engine.max_len)
-        specs = cache_specs(cfg, lanes, dp_axes=dp_axes,
-                            model_axis=model_axis, dp_size=dp_size,
-                            model_size=model_size)
-        engine.batches[variant].caches = jax.device_put(
-            lanes, to_shardings(submesh, specs))
+    lanes = init_cache(engine.cfg, engine.max_slots, engine.max_len)
+    specs = cache_specs(engine.cfg, lanes, dp_axes=dp_axes,
+                        model_axis=model_axis, dp_size=dp_size,
+                        model_size=model_size)
+    engine.batch.caches = jax.device_put(lanes,
+                                         to_shardings(submesh, specs))
 
 
 def build_router(cfg, params=None, *, genome: dict | None = None,
-                 max_len: int = 128, mesh=None, evolved_cfg=None,
-                 ab_fraction: float = 0.0, temperature: float = 0.0,
+                 max_len: int = 128, mesh=None, temperature: float = 0.0,
                  seed: int = 0, admit_max_wait: int = 32,
                  heartbeat_timeout: float = 8.0,
                  spans: Spans | None = None) -> Router:
@@ -398,7 +363,6 @@ def build_router(cfg, params=None, *, genome: dict | None = None,
         p = shard_replica_params(params, sm) if sm is not None else params
         eng = ServeEngine(cfg, p, max_len=max_len, max_slots=slots,
                           prefill_chunk=int(g["prefill_chunk"]),
-                          evolved_cfg=evolved_cfg, ab_fraction=ab_fraction,
                           temperature=temperature, seed=seed + i,
                           admit_max_wait=admit_max_wait, spans=spans)
         if sm is not None:

@@ -195,20 +195,18 @@ def _simulate_items(items, last_arrival: int, m: int, c: int,
             "n": n_done}
 
 
-def _engine_metrics(stats: dict, n_rejected: int, variant: str = "default"
-                    ) -> dict:
+def _engine_metrics(stats: dict, n_rejected: int) -> dict:
     """The canary metric vocabulary extracted from a real engine's
     ``stats()``."""
-    per = stats["per_variant"][variant]
     total = stats["n_completed"] + n_rejected
     return {"throughput_tok_s": stats["throughput_tok_s"],
-            "mean_ttft_s": per["mean_ttft_s"],
-            "mean_latency_s": per["mean_latency_s"],
+            "mean_ttft_s": stats["mean_ttft_s"],
+            "mean_latency_s": stats["mean_latency_s"],
             "reject_rate": round(n_rejected / total, 6) if total else 0.0,
             "gen_tokens": stats["gen_tokens"],
             "wall_s": stats["wall_s"],
-            "s_per_token": per["s_per_token"],
-            "n": per["n"]}
+            "s_per_token": stats["s_per_token"],
+            "n": stats["n_completed"]}
 
 
 # --------------------------------------------------------------------------

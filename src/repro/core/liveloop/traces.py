@@ -286,7 +286,7 @@ def trace_from_records(cache_path: str) -> dict[str, Trace]:
 
 
 # --------------------------------------------------------------------------
-# The demo trace (ported from core/deploy/engine.py, which now shims here)
+# The demo trace
 # --------------------------------------------------------------------------
 
 
@@ -294,8 +294,7 @@ def demo_requests(cfg, *, n_requests: int, prompt_len: int, gen: int,
                   seed: int = 0) -> list:
     """A deterministic mixed-length request list (prompt lengths alternate
     ``prompt_len`` and ``prompt_len // 2``) — the CLI demo / serving-A/B
-    trace, byte-compatible with the deprecated
-    ``repro.core.deploy.demo_trace``."""
+    trace."""
     from ..deploy.engine import ServeRequest
     rng = np.random.default_rng(seed)
     reqs = []
@@ -336,8 +335,8 @@ class ReplayReport:
 def replay(engine, trace: Trace, *, requests=None) -> ReplayReport:
     """Drive ``trace`` through ``engine`` honoring arrival ticks: each
     engine tick submits exactly the requests scheduled for it, then steps.
-    Requests the engine rejects (prompt + budget over ``max_len``, unknown
-    variant) are collected, not raised — a live loop must survive
+    Requests the engine rejects (prompt + budget over ``max_len``) are
+    collected, not raised — a live loop must survive
     malformed traffic.  ``requests`` overrides the materialized request
     list (callers that pre-routed or pre-filtered the trace)."""
     reqs = trace.requests() if requests is None else list(requests)

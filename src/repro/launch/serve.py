@@ -1,18 +1,19 @@
 """Serving launcher: the continuous-batching :class:`ServeEngine` as a CLI.
 
 Replays a deterministic mixed-length request trace (staggered arrivals)
-through the engine for any decoder arch, optionally routing between the
-default model configuration and an evolved artifact resolved from an
+through the engine for any decoder arch, serving either the default model
+configuration or an evolved plan artifact resolved from an
 :class:`~repro.core.deploy.ArtifactRegistry`, and optionally publishing the
-measured per-variant latency into a shared fitness cache under the ``serve``
-writer tag.
+measured latency into a shared fitness cache under the ``serve`` writer tag.
+(The live loop's shadow-replay canary is the A/B path: see
+``python -m repro.core.liveloop``.)
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --requests 8 --prompt-len 24 --gen 8
 
-  # engine schedule + evolved route resolved from the artifact registry
+  # engine schedule + evolved plan resolved from the artifact registry
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
-      --artifacts experiments/artifacts --variant ab --ab-fraction 0.5
+      --artifacts experiments/artifacts --variant evolved
 
   # the pre-engine one-shot behavior (correctness oracle)
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
@@ -33,7 +34,7 @@ from __future__ import annotations
 import argparse
 
 
-def main() -> None:
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -63,14 +64,13 @@ def main() -> None:
                     help="ArtifactRegistry directory (serve-schedule and "
                          "plan artifacts)")
     ap.add_argument("--variant", default="default",
-                    choices=("default", "evolved", "ab"),
-                    help="route requests to the default config, an evolved "
-                         "plan artifact, or an A/B mix")
-    ap.add_argument("--ab-fraction", type=float, default=0.5)
+                    choices=("default", "evolved"),
+                    help="serve the default config or an evolved plan "
+                         "artifact")
     ap.add_argument("--plan-shape", default="decode_32k",
                     help="shape key for resolving the plan artifact")
     ap.add_argument("--cache", default=None,
-                    help="publish per-variant latency records into this "
+                    help="publish a latency record into this "
                          "FitnessCache (JSONL) under writer tag 'serve'")
     ap.add_argument("--oneshot", action="store_true",
                     help="pre-engine one-shot path: batch prefill + "
@@ -82,7 +82,7 @@ def main() -> None:
                          "loop first")
     ap.add_argument("--liveloop-ticks", type=int, default=0,
                     help="control-loop ticks to run before serving")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from .compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -143,15 +143,14 @@ def main() -> None:
     if args.replicas is not None:
         schedule["replicas"] = args.replicas
 
-    evolved_cfg, ab = None, 0.0
-    if args.variant in ("evolved", "ab"):
+    served = cfg
+    if args.variant == "evolved":
         if plan_art is None:
             raise SystemExit(
-                f"--variant {args.variant} needs a plan artifact for "
+                f"--variant evolved needs a plan artifact for "
                 f"({cfg.name}, {args.plan_shape}); none registered under "
                 f"{args.artifacts or '--artifacts (not given)'}")
-        evolved_cfg = apply_plan_artifact(cfg, plan_art)
-        ab = 1.0 if args.variant == "evolved" else args.ab_fraction
+        served = apply_plan_artifact(cfg, plan_art)
 
     if int(schedule.get("replicas", 1)) > 1:
         mesh = None
@@ -159,16 +158,13 @@ def main() -> None:
             from .mesh import make_smoke_mesh
             d, m = (int(x) for x in args.mesh.lower().split("x"))
             mesh = make_smoke_mesh(d, m)
-        engine = build_router(cfg, genome=schedule,
+        engine = build_router(served, genome=schedule,
                               max_len=args.prompt_len + args.gen,
-                              mesh=mesh, evolved_cfg=evolved_cfg,
-                              ab_fraction=ab,
-                              temperature=args.temperature)
+                              mesh=mesh, temperature=args.temperature)
     else:
-        engine = ServeEngine(cfg, max_len=args.prompt_len + args.gen,
+        engine = ServeEngine(served, max_len=args.prompt_len + args.gen,
                              max_slots=schedule["max_slots"],
                              prefill_chunk=schedule["prefill_chunk"],
-                             evolved_cfg=evolved_cfg, ab_fraction=ab,
                              temperature=args.temperature)
     trace = demo_requests(cfg, n_requests=args.requests,
                           prompt_len=args.prompt_len, gen=args.gen)
@@ -177,28 +173,26 @@ def main() -> None:
     s = engine.stats()
     replica_note = (f" replicas={s['n_live']}/{s['n_replicas']}"
                     if "n_replicas" in s else "")
-    print(f"arch={cfg.name} requests={len(results)} "
-          f"schedule={schedule}{replica_note} "
+    print(f"arch={cfg.name} variant={args.variant} "
+          f"requests={len(results)} schedule={schedule}{replica_note} "
           f"ticks={s['ticks']}")
     print(f"wall={s['wall_s']:.2f}s throughput={s['throughput_tok_s']:.1f} "
-          f"tok/s")
-    for variant, rec in s["per_variant"].items():
-        if rec["n"] == 0:
-            continue
-        print(f"  [{variant}] n={rec['n']} "
-              f"ttft={rec['mean_ttft_s'] * 1e3:.1f}ms "
-              f"latency={rec['mean_latency_s'] * 1e3:.1f}ms "
-              f"(p95 {rec['p95_latency_s'] * 1e3:.1f}ms) "
-              f"s/token={rec['s_per_token'] * 1e3:.1f}ms")
+          f"tok/s ttft={s['mean_ttft_s'] * 1e3:.1f}ms "
+          f"latency={s['mean_latency_s'] * 1e3:.1f}ms "
+          f"(p95 {s['p95_latency_s'] * 1e3:.1f}ms) "
+          f"s/token={s['s_per_token'] * 1e3:.1f}ms")
     for r in results[:2]:
-        print(f"  {r.uid} [{r.variant}]: {r.tokens[:12]}...")
+        print(f"  {r.uid}: {r.tokens[:12]}...")
 
     if args.cache:
         cache = FitnessCache(args.cache, writer="serve")
+        # the run tag keeps an evolved plan's record apart from the
+        # default's under the same schedule
         keys = engine.publish_stats(
             cache, name=cfg.name,
             shape={"prompt_len": args.prompt_len, "gen": args.gen,
-                   "smoke": args.smoke})
+                   "smoke": args.smoke},
+            run="" if args.variant == "default" else args.variant)
         cache.close()
         print(f"published {len(keys)} serve-tagged latency records to "
               f"{args.cache}")

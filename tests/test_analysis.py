@@ -102,6 +102,26 @@ def test_fold_constants_is_bit_exact():
         sum(op.opcode == "add" for op in p.ops)
 
 
+def test_fold_keeps_scalar_type():
+    """A reshape of a (1, 1) constant to a scalar folds to a 0-d constant,
+    matching the op's recorded scalar type."""
+    b = Builder("fold_scalar")
+    x = b.input("x", (2, 3))
+    s = b.reshape(b.const(np.full((1, 1), 1.5, np.float32)), ())
+    b.output(s, b.add(x, b.broadcast(s, (2, 3), ())))
+    p = b.done()
+    folded = fold_constants(p)
+    folded.verify()
+    (c,) = [op for op in folded.ops if op.result == s]
+    assert c.opcode == "constant" and c.attrs["value"].shape == ()
+    inp = {"x": np.random.RandomState(4).randn(2, 3).astype(np.float32)}
+    want = [np.asarray(o) for o in evaluate(p, inp)]
+    got = [np.asarray(o) for o in evaluate(folded, inp)]
+    for u, v in zip(want, got):
+        assert u.dtype == v.dtype and u.shape == v.shape
+        assert np.array_equal(u, v)
+
+
 def test_canonical_fingerprint_ignores_dead_code_and_uids():
     p = _mlp()
     f0 = canonical_fingerprint(normalize(p))

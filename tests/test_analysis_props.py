@@ -9,7 +9,7 @@ import pytest
 pytest.importorskip("hypothesis",
                     reason="property tests need hypothesis (pip install "
                            ".[test])")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.analysis import (canonical_fingerprint, eliminate_dead,
                                  fold_constants, make_screen, normalize)
@@ -52,6 +52,9 @@ def _outs(program, inputs):
 
 @settings(max_examples=40, deadline=None)
 @given(seed=st.integers(0, 10_000))
+@example(seed=595)   # these three fold a reshape to a scalar
+@example(seed=679)
+@example(seed=826)
 def test_passes_preserve_interp_bit_exactly(seed):
     """eliminate_dead / fold_constants / normalize on a random mutant leave
     the interpreted outputs bit-identical (not merely allclose)."""

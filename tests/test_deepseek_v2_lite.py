@@ -64,7 +64,8 @@ class _Recording(ServeEngine):
         self.seen: dict[str, list] = {}
 
     def _decode_complete(self, pending):
-        for _, active, logits, _ in pending:
+        if pending is not None:
+            active, logits, _ = pending
             rows = np.asarray(logits)
             for i, lane in active:
                 self.seen.setdefault(lane.req.uid, []).append(

@@ -216,11 +216,8 @@ class TestFaultInjection:
         router = _two_replica_router(cfg, params, max_len=8)
         assert not router.try_submit(ServeRequest(
             uid="big", tokens=np.zeros(8, np.int32), max_new_tokens=4))
-        assert not router.try_submit(ServeRequest(
-            uid="v", tokens=np.zeros(2, np.int32), max_new_tokens=2,
-            variant="evolved"))
-        assert router.n_rejected == 2
-        assert router.rejected_uids == ["big", "v"]
+        assert router.n_rejected == 1
+        assert router.rejected_uids == ["big"]
 
 
 class TestRouterFeedback:
@@ -247,6 +244,10 @@ class TestRouterFeedback:
         cache.close()
         assert k_router and k_single
         assert not (set(k_router) & set(k_single))
+        # this plan's key as it was when the engine also had an evolved
+        # route: stores written then keep de-duplicating
+        assert k_router == ["serve:8ecb7e789e2ba053a1a67ae88bb366c5e947aa03"
+                            "cd1e52fbd26ead22d7244cca"]
         assert k_again == []            # first write wins, dedupe holds
 
     def test_fresh_router_stats_are_zeros(self, qwen):
@@ -255,8 +256,22 @@ class TestRouterFeedback:
         s = router.stats()
         assert s["n_completed"] == 0 and s["wall_s"] == 0.0
         assert s["throughput_tok_s"] == 0.0
-        assert s["per_variant"]["default"]["n"] == 0
+        assert s["gen_tokens"] == 0 and s["mean_latency_s"] == 0.0
+        assert s["mean_ttft_s"] == 0.0 and s["s_per_token"] == 0.0
         assert len(s["per_replica"]) == 2
+
+    def test_router_summary_matches_engine(self, qwen):
+        """A one-replica router summarises the same finished requests as
+        its engine, so their latency keys agree exactly."""
+        cfg, params = qwen
+        router = build_router(cfg, params, genome={"replicas": 1},
+                              max_len=12)
+        router.run(_reqs(_prompts(cfg, (6, 4, 6, 5), seed=9), 3), stagger=1)
+        keys = ("n_completed", "gen_tokens", "mean_latency_s",
+                "p95_latency_s", "mean_ttft_s", "s_per_token")
+        want = {k: router.replicas[0].engine.stats()[k] for k in keys}
+        assert want["n_completed"] == 4 and want["mean_latency_s"] > 0
+        assert {k: router.stats()[k] for k in keys} == want
 
 
 class TestLiveLoopPlanCanary:
@@ -350,15 +365,14 @@ cfg = smoke_config("qwen3-0.6b")
 params = init_params(cfg, jax.random.PRNGKey(0))
 router = build_router(cfg, params, genome={"replicas": 2, "max_slots": 2},
                       max_len=16, mesh=make_smoke_mesh(2, 2))
-specs = [[str(ax) for ax in b.caches[n].sharding.spec]
-         for r in router.replicas for b in r.engine.batches.values()
-         for n in ("k", "v")]
+specs = [[str(ax) for ax in r.engine.batch.caches[n].sharding.spec]
+         for r in router.replicas for n in ("k", "v")]
 rng = np.random.default_rng(3)
 prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in (8, 5, 8)]
 out = {r.uid: r.tokens for r in router.run(
     [ServeRequest(uid=str(i), tokens=p, max_new_tokens=4)
      for i, p in enumerate(prompts)], stagger=1)}
-lanes = router.replicas[0].engine.batches["default"].caches
+lanes = router.replicas[0].engine.batch.caches
 print(json.dumps({
     "specs": specs, "shape": list(lanes["k"].shape),
     "served": [out[str(i)] for i in range(len(prompts))],

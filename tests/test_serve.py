@@ -1,6 +1,6 @@
 """First serving coverage: the continuous-batching ServeEngine against the
-one-shot/unbatched oracle, registry-routed variants, and the serve-tagged
-latency feedback into the shared FitnessCache."""
+one-shot/unbatched oracle, registry-resolved schedules and plans, and the
+serve-tagged latency feedback into the shared FitnessCache."""
 
 import json
 
@@ -10,8 +10,8 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.deploy import (Artifact, ArtifactRegistry, ServeEngine,
-                               ServeRequest, oneshot_generate,
-                               serve_schedule_space)
+                               ServeRequest, apply_plan_artifact,
+                               oneshot_generate, serve_schedule_space)
 from repro.core.evaluator import FitnessCache
 from repro.core.liveloop.traces import demo_requests
 from repro.models.transformer import greedy_reference, init_params
@@ -116,36 +116,58 @@ class TestEngineCorrectness:
         with pytest.raises(ValueError, match="exceeds max_len"):
             eng.submit(ServeRequest(uid="big", tokens=np.zeros(6, np.int32),
                                     max_new_tokens=4))
-        with pytest.raises(ValueError, match="unknown variant"):
-            eng.submit(ServeRequest(uid="v", tokens=np.zeros(2, np.int32),
-                                    max_new_tokens=2, variant="evolved"))
 
 
-class TestVariantRouting:
-    def test_ab_routes_both_variants(self, qwen):
+_EVOLVED_PLAN = {"attn_impl": "blockwise", "attn_block": 8}
+
+
+class TestEvolvedPlan:
+    def test_evolved_plan_serves_same_tokens(self, qwen):
+        """An engine on an evolved plan (blockwise attention over the same
+        weights) serves the default engine's greedy tokens on the same
+        staggered prompts."""
         cfg, params = qwen
-        evolved = cfg.scaled(attn_impl="blockwise", attn_block=8)
-        eng = ServeEngine(cfg, params, max_len=12, max_slots=4,
-                          prefill_chunk=2, evolved_cfg=evolved,
-                          ab_fraction=0.5, seed=7)
-        reqs = [ServeRequest(uid=f"r{i}", tokens=p, max_new_tokens=3)
-                for i, p in enumerate(_prompts(cfg, (8,) * 8, seed=4))]
-        out = eng.run(reqs, stagger=3)
-        variants = {r.variant for r in out}
-        assert variants == {"default", "evolved"}
-        per = eng.stats()["per_variant"]
-        assert per["default"]["n"] + per["evolved"]["n"] == 8
+        evolved = apply_plan_artifact(cfg, Artifact(
+            kind="plan", name=cfg.name, shape="decode_32k",
+            genome=_EVOLVED_PLAN))
+        assert (evolved.attn_impl, evolved.attn_block) == ("blockwise", 8)
+        assert cfg.attn_impl != "blockwise"
+        prompts = _prompts(cfg, (12, 5, 9, 12, 7), seed=4)
 
-    def test_pinned_variant_wins_over_fraction(self, qwen):
-        cfg, params = qwen
-        evolved = cfg.scaled(attn_impl="blockwise", attn_block=8)
-        eng = ServeEngine(cfg, params, max_len=12, max_slots=2,
-                          prefill_chunk=2, evolved_cfg=evolved,
-                          ab_fraction=1.0)
-        (p,) = _prompts(cfg, (8,), seed=5)
-        out = eng.run([ServeRequest(uid="pin", tokens=p, max_new_tokens=2,
-                                    variant="default")])
-        assert out[0].variant == "default"
+        def run(c):
+            eng = ServeEngine(c, params, max_len=16, max_slots=3,
+                              prefill_chunk=2)
+            reqs = [ServeRequest(uid=f"r{i}", tokens=p, max_new_tokens=4)
+                    for i, p in enumerate(prompts)]
+            return {r.uid: r.tokens for r in eng.run(reqs, stagger=2)}
+
+        want = run(cfg)
+        assert len(want) == len(prompts)
+        assert run(evolved) == want
+
+    def test_launcher_serves_evolved_plan(self, tmp_path, capsys,
+                                          monkeypatch):
+        """``launch/serve.py --variant evolved`` serves the registry's plan
+        artifact and completes every request; without one it exits with
+        the reason."""
+        import repro.launch.compile_cache as compile_cache
+        from repro.launch.serve import main
+        # leave this process's JAX configuration as the other tests see it
+        monkeypatch.setattr(compile_cache, "enable_compile_cache",
+                            lambda: "")
+        cfg = smoke_config("qwen3-0.6b")
+        arts = str(tmp_path / "arts")
+        argv = ["--arch", "qwen3-0.6b", "--smoke", "--requests", "4",
+                "--prompt-len", "8", "--gen", "3", "--artifacts", arts,
+                "--variant", "evolved"]
+        with pytest.raises(SystemExit, match="needs a plan artifact"):
+            main(argv)
+        ArtifactRegistry(arts).export(Artifact(
+            kind="plan", name=cfg.name, shape="decode_32k",
+            genome=_EVOLVED_PLAN))
+        main(argv)
+        out = capsys.readouterr().out
+        assert "variant=evolved requests=4 " in out
 
 
 class TestServeFeedback:
@@ -163,7 +185,10 @@ class TestServeFeedback:
                                  shape={"prompt_len": 8, "gen": 3},
                                  run="unit")
         cache.close()
-        assert keys and all(k.startswith("serve:") for k in keys)
+        # this configuration's key as it was when the engine also had an
+        # evolved route: stores written then keep de-duplicating
+        assert keys == ["serve:3bfc43f92e06b6df2429dd677461e1f8d15037eeb1e"
+                        "fbe97f4eebc57210500c2"]
         recs = [json.loads(line) for line in open(path)]
         assert len(recs) == len(keys)
         for rec in recs:
@@ -231,8 +256,7 @@ class TestServeSearchSurface:
 
 class TestStatsHardening:
     """stats()/publish_stats() on the degenerate paths the live loop hits:
-    fresh engines, mid-run reads, all-rejected admissions, zero-completion
-    variants."""
+    fresh engines, mid-run reads, all-rejected admissions."""
 
     def test_fresh_engine_stats_are_zeros(self, qwen):
         cfg, params = qwen
@@ -240,7 +264,8 @@ class TestStatsHardening:
         s = eng.stats()
         assert s["wall_s"] == 0.0 and s["throughput_tok_s"] == 0.0
         assert s["n_completed"] == 0 and s["n_rejected"] == 0
-        assert s["per_variant"]["default"]["n"] == 0
+        assert s["gen_tokens"] == 0 and s["mean_latency_s"] == 0.0
+        assert s["mean_ttft_s"] == 0.0 and s["s_per_token"] == 0.0
 
     def test_midrun_stats_never_negative(self, qwen):
         """Regression: a stats() read after the first tick but before any
@@ -262,26 +287,9 @@ class TestStatsHardening:
             uid="ok", tokens=np.zeros(2, np.int32), max_new_tokens=2))
         big = eng.try_submit(ServeRequest(
             uid="big", tokens=np.zeros(8, np.int32), max_new_tokens=4))
-        bad_v = eng.try_submit(ServeRequest(
-            uid="v", tokens=np.zeros(2, np.int32), max_new_tokens=2,
-            variant="evolved"))
-        assert ok and not big and not bad_v
-        assert eng.n_rejected == 2
-        assert eng.stats()["n_rejected"] == 2
-
-    def test_publish_skips_empty_variants(self, qwen, tmp_path):
-        """A variant that completed nothing is a zeroed stats row, not a
-        published 'measurement' of zero latency."""
-        cfg, params = qwen
-        evolved = cfg.scaled(attn_impl="blockwise", attn_block=8)
-        eng = ServeEngine(cfg, params, max_len=12, evolved_cfg=evolved,
-                          ab_fraction=0.0)     # all traffic -> default
-        eng.run(demo_requests(cfg, n_requests=2, prompt_len=6, gen=2))
-        assert eng.stats()["per_variant"]["evolved"]["n"] == 0
-        cache = FitnessCache(str(tmp_path / "c.jsonl"), writer="serve")
-        keys = eng.publish_stats(cache, name=cfg.name, shape="s")
-        cache.close()
-        assert len(keys) == 1
+        assert ok and not big
+        assert eng.n_rejected == 1
+        assert eng.stats()["n_rejected"] == 1
 
     def test_publish_nothing_when_idle(self, qwen, tmp_path):
         cfg, params = qwen
@@ -374,18 +382,3 @@ class TestAdmissionAging:
         cfg, params = qwen
         with pytest.raises(ValueError, match="admit_max_wait"):
             ServeEngine(cfg, params, max_len=12, admit_max_wait=0)
-
-
-class TestDemoTraceShim:
-    def test_deprecated_shim_matches_demo_requests(self, qwen):
-        """repro.core.deploy.demo_trace is a deprecation shim now: it must
-        warn, and return exactly what liveloop's demo_requests returns."""
-        from repro.core.deploy import demo_trace
-        cfg, _ = qwen
-        with pytest.warns(DeprecationWarning, match="demo_requests"):
-            old = demo_trace(cfg, n_requests=3, prompt_len=8, gen=3)
-        new = demo_requests(cfg, n_requests=3, prompt_len=8, gen=3)
-        assert [r.uid for r in old] == [r.uid for r in new]
-        for a, b in zip(old, new):
-            assert np.array_equal(a.tokens, b.tokens)
-            assert a.max_new_tokens == b.max_new_tokens

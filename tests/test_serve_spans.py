@@ -113,7 +113,7 @@ class TestEngineSpans:
         assert len(_of(spans, "tick")) == eng.n_ticks
         assert len(_of(spans, "prefill")) == eng.n_prefill_batches
         # the first two prompts share a length and prefill as one batch
-        assert eng.n_prefill_batches < len(_reqs(eng.cfgs["default"]))
+        assert eng.n_prefill_batches < len(_reqs(eng.cfg))
         assert len(_of(spans, "decode")) == eng.n_decode_batches
         for name in NAMES:
             assert all(a <= b for a, b in _of(spans, name)), name
